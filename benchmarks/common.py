@@ -8,6 +8,7 @@ Benchmarks run on the CPU backend with 8 placeholder devices (set by
 
 from __future__ import annotations
 
+import os
 import time
 from typing import Callable, Dict, List, Optional
 
@@ -15,6 +16,25 @@ import jax
 import numpy as np
 
 RESULTS: List[Dict] = []
+
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    A ``JAX_COMPILATION_CACHE_DIR`` set by the caller is JAX's own setting
+    and is left alone.  Otherwise the cache lives at the fixed path
+    ``<repo>/.jax_cache``: the directory is part of the cache key, so a
+    path that changed from run to run would never hit.  Entry points call
+    this; importing the library never does.
+    """
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if path:
+        return path
+    path = os.path.join(REPO_ROOT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def time_fn(fn: Callable, *args, warmup: int = 2, iters: int = 5,

@@ -42,7 +42,9 @@ def main() -> None:
                    bench_kernels, bench_local_ops, bench_moe_shuffle,
                    bench_pipeline, bench_shuffle_impl, bench_skew,
                    bench_strong_scaling)
-    from .common import RESULTS, dump_csv, dump_json
+    from .common import RESULTS, dump_csv, dump_json, enable_compile_cache
+
+    enable_compile_cache()
 
     scale = 50 if args.smoke else 4 if args.quick else 1
     suites = {

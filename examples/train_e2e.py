@@ -18,7 +18,6 @@ import argparse
 import time
 
 import jax
-from repro import compat
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
@@ -72,7 +71,7 @@ print(f"[train] params={n_params / 1e6:.1f}M mesh={dict(zip(mesh.axis_names, mes
 
 step_fn = jax.jit(make_train_step(CFG, opt, rules, ce_chunk=128))
 losses = []
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     for step in range(args.steps):
         batch = {k: jnp.asarray(v) for k, v in next(batches).items()}
         t0 = time.time()

@@ -34,7 +34,6 @@ from typing import Any, Callable, Dict, Type
 import jax
 import jax.numpy as jnp
 
-from .. import compat
 
 
 class Communicator(abc.ABC):
@@ -50,7 +49,7 @@ class Communicator(abc.ABC):
     # Introspection (valid inside shard_map only)
     # ------------------------------------------------------------------ #
     def size(self) -> int:
-        return compat.axis_size(self.axis)
+        return jax.lax.axis_size(self.axis)
 
     def rank(self):
         return jax.lax.axis_index(self.axis)

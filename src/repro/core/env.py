@@ -25,14 +25,27 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from .. import compat
 from ..comm import Communicator, get_communicator
 from ..dataframe.table import Table
 from ..obs.trace import NULL_TRACER
 
 AXIS = "df"  # default dataframe axis name
+
+
+def put_rows(rows: np.ndarray,
+             mesh: Optional[jax.sharding.Mesh]) -> jax.Array:
+    """Host rows ``(p * capacity, ...)`` -> a device array.
+
+    With the gang's ``mesh``, rank r's block is copied straight to device
+    r.  Without one (the gang is not known yet) the array lands on the
+    default device and the first program that takes it moves the blocks,
+    so that device briefly holds the whole table."""
+    if mesh is None:
+        return jnp.asarray(rows)
+    return jax.device_put(rows, NamedSharding(mesh, P(mesh.axis_names[0])))
 
 
 # ---------------------------------------------------------------------- #
@@ -68,8 +81,10 @@ class DistTable:
 
     @classmethod
     def from_numpy(cls, data: Dict[str, np.ndarray], parallelism: int,
-                   capacity: Optional[int] = None) -> "DistTable":
-        """Block-distribute host rows over ``parallelism`` shards.
+                   capacity: Optional[int] = None,
+                   mesh: Optional[jax.sharding.Mesh] = None) -> "DistTable":
+        """Block-distribute host rows over ``parallelism`` shards (placed
+        on ``mesh``'s devices when given, see ``put_rows``).
 
         String columns (object / unicode numpy arrays) are dictionary-
         encoded host-side: the device gets int32 codes, the sorted
@@ -98,8 +113,9 @@ class DistTable:
                 chunk = arr[r * per:(r + 1) * per]
                 buf[r, :len(chunk)] = chunk
                 counts[r] = len(chunk)
-            cols[name] = jnp.asarray(buf.reshape((parallelism * capacity,) + arr.shape[1:]))
-        return cls(cols, jnp.asarray(counts), capacity, dicts)
+            cols[name] = put_rows(
+                buf.reshape((parallelism * capacity,) + arr.shape[1:]), mesh)
+        return cls(cols, put_rows(counts, mesh), capacity, dicts)
 
     def to_numpy(self, decode: bool = True, nulls: str = "pandas"
                  ) -> Dict[str, np.ndarray]:
@@ -181,6 +197,7 @@ class MorselSource:
             faults = NULL_FAULTS
         self._faults = faults
         self._token = token
+        self._mesh = env.mesh if env is not None else None
 
     def _build(self, m: int) -> Optional[DistTable]:
         if m >= self.num_morsels:
@@ -199,11 +216,12 @@ class MorselSource:
                 buf[r, :len(piece)] = piece
                 counts[r] = len(piece)
             self.h2d_bytes += buf.nbytes
-            cols[name] = jnp.asarray(buf.reshape((p * cap,) + ref.shape[1:]))
+            cols[name] = put_rows(buf.reshape((p * cap,) + ref.shape[1:]),
+                                  self._mesh)
         self.h2d_bytes += counts.nbytes
         self._tracer.instant(f"h2d:morsel[{m}]", "transfer", morsel=m,
                              bytes=self.h2d_bytes - b0)
-        return DistTable(cols, jnp.asarray(counts), cap,
+        return DistTable(cols, put_rows(counts, self._mesh), cap,
                          dict(self.spill.dictionaries))
 
     def __iter__(self):
@@ -385,7 +403,7 @@ class CylonEnv:
         # per-shard axis (columns (cap,...), counts (1,), arrays (1,...)), so
         # a single P(axis) applies to the whole output tree and no separate
         # structure-discovery trace is needed.
-        mapped = jax.jit(compat.shard_map(
+        mapped = jax.jit(jax.shard_map(
             shard_body, mesh=self.mesh, in_specs=in_specs,
             out_specs=P(self.axis), check_vma=False))
 
@@ -428,7 +446,7 @@ class EnvContext:
         return jax.lax.axis_index(self.axis)
 
     def size(self):
-        return compat.axis_size(self.axis)
+        return jax.lax.axis_size(self.axis)
 
 
 # ---------------------------------------------------------------------- #

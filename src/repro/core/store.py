@@ -26,11 +26,11 @@ import threading
 import time
 from typing import Dict, List, Mapping, Optional, Tuple, Union
 
-import jax.numpy as jnp
+import jax
 import numpy as np
 
 from ..obs.trace import NULL_TRACER
-from .env import DistTable
+from .env import DistTable, put_rows
 
 
 def _round8(x: int) -> int:
@@ -356,8 +356,9 @@ def respill_routed(spill: SpillTable, dest_of,
 # ---------------------------------------------------------------------- #
 def rescatter(spill: SpillTable, parallelism: int,
               capacity: Optional[int] = None,
-              tracer=NULL_TRACER) -> DistTable:
-    """SpillTable -> DistTable over a (possibly different) gang size.
+              tracer=NULL_TRACER, mesh=None) -> DistTable:
+    """SpillTable -> DistTable over a (possibly different) gang size,
+    placed on ``mesh``'s devices when given (``core.env.put_rows``).
 
     Rows are routed chunk-by-chunk into per-destination host buckets by
     their global block index — no rank's data is ever concatenated into a
@@ -374,7 +375,7 @@ def rescatter(spill: SpillTable, parallelism: int,
         raise ValueError(f"rows/shard {per} exceeds capacity {cap}")
     schema = spill.schema
     buckets = _route_chunks(spill, parallelism)
-    cols: Dict[str, jnp.ndarray] = {}
+    cols: Dict[str, jax.Array] = {}
     counts = np.zeros((parallelism,), np.int32)
     for name, (dtype, trail) in schema.items():
         buf = np.zeros((parallelism, cap) + trail, dtype)
@@ -385,9 +386,9 @@ def rescatter(spill: SpillTable, parallelism: int,
                 buf[d, pos:pos + len(v)] = v
                 pos += len(v)
             counts[d] = pos
-        cols[name] = jnp.asarray(
-            buf.reshape((parallelism * cap,) + trail))
-    return DistTable(cols, jnp.asarray(counts), cap,
+        cols[name] = put_rows(buf.reshape((parallelism * cap,) + trail),
+                              mesh)
+    return DistTable(cols, put_rows(counts, mesh), cap,
                      dict(spill.dictionaries),
                      provenance=spill.provenance)
 

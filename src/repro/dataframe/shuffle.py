@@ -23,8 +23,8 @@ Two bucketize/compaction implementations (``impl``):
   direct scatter of the u32-packed rows — each row is touched exactly once,
   no ``argsort``/gather.  Receive side: exclusive prefix sums over
   ``recv_counts`` give every received row its output slot, so compaction
-  is a single O(n) masked scatter.  Pallas kernel on TPU, the segment-
-  cumsum XLA path elsewhere.
+  is a single O(n) masked scatter.  The partition is the segment-cumsum
+  XLA path on every backend (``kernels/radix_partition/ops.py``).
 * ``"sorted"`` — the original two-``argsort`` implementation
   (O(n log n) send-side bucketize + O(n log n) receive-side compaction),
   kept as the parity oracle and benchmark baseline.
@@ -192,7 +192,7 @@ def shuffle(
     # --- bucketize: per-row send-buffer slot ----------------------------- #
     if impl == "radix":
         # sort-free: stable rank within destination bucket + histogram in
-        # one kernel pass (Pallas on TPU, segment-cumsum XLA path elsewhere)
+        # one pass of the segment-cumsum XLA path
         ranks, hist = radix_partition(dest, p + 1)
         raw_counts = hist[:p]
         row_rank = ranks

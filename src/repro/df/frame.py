@@ -453,7 +453,9 @@ def read_numpy(data: Mapping[str, np.ndarray], *,
     else:
         if chunk_rows is not None:
             raise TypeError("chunk_rows only applies with spill=True")
-        table = DistTable.from_numpy(dict(data), p, capacity)
+        table = DistTable.from_numpy(dict(data), p, capacity,
+                                     mesh=env.mesh if env is not None
+                                     else None)
     return from_table(table, name, env)
 
 

@@ -2,14 +2,15 @@
 
 Implementation selection (``impl``):
 
-* ``"auto"``   — the compiled Pallas kernel on TPU; the sort-free XLA
-                 segment-cumsum path (``xla.py``) everywhere else.  The XLA
-                 path is pure ``jnp``, so ``auto`` is always safe inside
-                 ``shard_map`` / ``vmap`` regions (interpret-mode
-                 ``pallas_call`` is not) — this is what the dataframe
-                 shuffle uses.
-* ``"pallas"`` — force the Pallas kernel (interpret mode off-TPU; tests).
-* ``"xla"``    — force the sort-free XLA path.
+* ``"auto"``   — the sort-free XLA segment-cumsum path (``xla.py``) on
+                 every backend; this is what the dataframe shuffle uses.
+                 It is pure ``jnp``, so it is safe inside ``shard_map`` /
+                 ``vmap`` regions, and it compiles for a v5e at 2^24 rows
+                 where the Pallas kernel's lane-padded layout does not fit
+                 in HBM (``radix_partition.py``).  No chip measurement has
+                 favoured the kernel yet.
+* ``"pallas"`` — the Pallas kernel (interpret mode off-TPU).
+* ``"xla"``    — the same as ``"auto"``.
 * ``"ref"``    — the sort-based jnp oracle (``ref.py``).
 """
 
@@ -33,9 +34,7 @@ def radix_partition(dest: jax.Array, num_buckets: int, block_rows: int = 256,
     """(ranks, hist) for destination buckets; see module docstring for ``impl``."""
     if not use_kernel or impl == "ref":
         return radix_partition_ref(dest, num_buckets)
-    if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "xla"
-    if impl == "xla":
+    if impl in ("auto", "xla"):
         return radix_partition_xla(dest, num_buckets)
     if impl != "pallas":
         raise ValueError(f"unknown radix_partition impl {impl!r}")

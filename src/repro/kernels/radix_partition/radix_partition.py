@@ -12,8 +12,16 @@ destination matrix — all VPU/MXU-friendly dense ops.
   rank[i]  = running[dest_i] + (# earlier rows in this block with dest_i)
   hist     = running counts after the last block
 
-Block sizes: R rows × NB buckets one-hot (256×1024 i32 = 1 MiB) well inside
-VMEM; NB is padded to a multiple of 128 lanes.
+Mosaic has no lowering for ``cumsum``, so the in-block exclusive prefix is
+a matmul with a strictly-lower-triangular 0/1 matrix: the 0/1 operands are
+exact in bf16 and the counts (at most R) exact in the f32 accumulator.
+
+Layout: the one-hot block (R × NB) sits in VMEM, but the ``(n, 1)`` int32
+input and rank arrays are padded to 128 lanes in HBM — 512 bytes per row.
+At 2^20 rows that is a 1 GiB temporary against a 4 MiB input, and at 2^24
+rows the program no longer fits a 16 GB v5e.  ``ops.radix_partition``
+therefore sends the shuffle to the XLA path; this kernel stays as
+``impl="pallas"`` until a lane-dense layout is measured against it.
 """
 
 from __future__ import annotations
@@ -40,8 +48,13 @@ def _kernel(dest_ref, rank_ref, hist_ref, running_ref):
     r, nb = dest.shape[0], running_ref.shape[1]
     cols = jax.lax.broadcasted_iota(jnp.int32, (r, nb), 1)
     onehot = (cols == dest).astype(jnp.int32)  # (R, NB)
-    # stable rank within block: exclusive cumsum down the rows
-    excl = jnp.cumsum(onehot, axis=0) - onehot
+    # stable rank within block: exclusive prefix sum down the rows, as
+    # strictly-lower-triangular (R, R) @ one-hot (R, NB) on the MXU
+    row = jax.lax.broadcasted_iota(jnp.int32, (r, r), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (r, r), 1)
+    lower = (col < row).astype(jnp.bfloat16)
+    excl = jnp.dot(lower, onehot.astype(jnp.bfloat16),
+                   preferred_element_type=jnp.float32).astype(jnp.int32)
     in_block = jnp.sum(excl * onehot, axis=1, keepdims=True)       # (R, 1)
     carried = jnp.sum(running_ref[...] * onehot, axis=1, keepdims=True)
     rank_ref[...] = carried + in_block

@@ -1,4 +1,4 @@
-"""Sort-free XLA formulation of radix partition (the CPU/GPU hot path).
+"""Sort-free XLA formulation of radix partition (the shuffle's hot path).
 
 ``radix_partition_ref`` is the sort-based oracle (two O(n log n) passes —
 exactly the cost the sort-free shuffle removes).  This module computes the
@@ -17,8 +17,8 @@ materialisation at scale:
   the running per-bucket histogram — the same structure as the Pallas TPU
   kernel, with peak memory O(block_rows · nb) instead of O(n · nb).
 
-Used by ``ops.radix_partition`` on every non-TPU backend and by the
-dataframe shuffle's scatter (it is pure ``jnp``, so it is safe under
+Used by ``ops.radix_partition(impl="auto")`` on every backend, and so by
+the dataframe shuffle's scatter (it is pure ``jnp``, so it is safe under
 ``shard_map`` / ``vmap`` where an interpret-mode ``pallas_call`` is not).
 """
 

@@ -33,7 +33,6 @@ from functools import partial
 from typing import Any, Dict, Optional
 
 import jax
-from .. import compat
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -92,7 +91,7 @@ def build_lowered(c: Cell, mesh, ce_chunk: int = 512,
         batch_shapes = train_batch_specs(cfg, c.global_batch, c.seq_len)
         st_specs = state_specs(cfg, rules)
         b_specs = batch_spec_tree(cfg, rules)
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(
                 step,
                 in_shardings=(_named(mesh, st_specs), _named(mesh, b_specs)),
@@ -113,7 +112,7 @@ def build_lowered(c: Cell, mesh, ce_chunk: int = 512,
         batch_shapes = prefill_batch_specs(cfg, c.global_batch, c.seq_len)
         b_specs = {k: v for k, v in batch_spec_tree(cfg, rules).items()
                    if k in batch_shapes}
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(
                 fn,
                 in_shardings=(_named(mesh, p_specs), _named(mesh, b_specs)),
@@ -128,7 +127,7 @@ def build_lowered(c: Cell, mesh, ce_chunk: int = 512,
     tok_sh, pos_sh = decode_token_specs(cfg, c.global_batch)
     tok_spec = P(rules.batch, None, None) if cfg.family == "audio" \
         else P(rules.batch, None)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(
             fn,
             in_shardings=(_named(mesh, p_specs), _named(mesh, c_specs),

@@ -2,9 +2,11 @@
 
 Per (arch × shape × mesh) cell:
 
-  compute term    = HLO_FLOPs / (chips × 197e12 bf16 FLOP/s)
-  memory term     = HLO_bytes / (chips × 819e9 B/s HBM)
-  collective term = wire_bytes / (chips × 50e9 B/s ICI link)
+  compute term    = HLO_FLOPs / (chips × peak bf16 FLOP/s)
+  memory term     = HLO_bytes / (chips × peak HBM B/s)
+  collective term = wire_bytes / (chips × ICI link B/s)
+
+with the peaks of the chip's ``device_kind`` from ``PEAKS``.
 
 ``cost_analysis()`` runs on the *partitioned* (per-device SPMD) module, so
 its flops/bytes are per-device; multiplying by chips gives the global
@@ -31,10 +33,26 @@ import json
 import re
 from typing import Any, Dict, List, Optional, Tuple
 
-# TPU v5e-class hardware constants (assignment-specified)
-PEAK_FLOPS = 197e12     # bf16 FLOP/s per chip
-HBM_BW = 819e9          # B/s per chip
-LINK_BW = 50e9          # B/s ICI per chip
+#: Per-chip peaks keyed by ``jax.Device.device_kind``.  Source: Google
+#: Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s,
+#: 1,600 Gbit/s of inter-chip interconnect over 4 links (50 GB/s a link,
+#: the collective model's per-link bandwidth).
+PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "link_bw": 50e9},
+}
+#: the dry-run's compile target
+V5E = "TPU v5 lite"
+
+
+def device_peaks(device_kind: str) -> Dict[str, float]:
+    """Peaks of one chip; a kind missing from ``PEAKS`` is an error, never
+    a default (a roofline against the wrong chip is a wrong number)."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; add it to PEAKS with its "
+                         f"source") from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -131,10 +149,12 @@ def model_flops(cfg, kind: str, global_batch: int, seq_len: int) -> float:
 
 
 def roofline_terms(per_device_flops: float, per_device_bytes: float,
-                   per_device_wire_bytes: float) -> Dict[str, float]:
-    compute_s = per_device_flops / PEAK_FLOPS
-    memory_s = per_device_bytes / HBM_BW
-    collective_s = per_device_wire_bytes / LINK_BW
+                   per_device_wire_bytes: float,
+                   device_kind: str = V5E) -> Dict[str, float]:
+    peak = device_peaks(device_kind)
+    compute_s = per_device_flops / peak["flops"]
+    memory_s = per_device_bytes / peak["hbm_bw"]
+    collective_s = per_device_wire_bytes / peak["link_bw"]
     terms = {"compute_s": compute_s, "memory_s": memory_s,
              "collective_s": collective_s}
     dominant = max(terms, key=terms.get)
@@ -144,9 +164,10 @@ def roofline_terms(per_device_flops: float, per_device_bytes: float,
 
 
 def stage_roofline(wire_bytes: float, elapsed_s: Optional[float],
-                   parallelism: int,
+                   parallelism: int, device_kind: str,
                    hbm_bytes: Optional[float] = None) -> Dict[str, float]:
-    """Roofline terms for one *measured* query stage (``repro.obs``).
+    """Roofline terms for one *measured* query stage (``repro.obs``) on a
+    chip of ``device_kind``.
 
     ``wire_bytes`` is the stage's global shuffle volume (from
     ``ExecStats.shuffle_records``); ``hbm_bytes`` defaults to 2x wire (every
@@ -161,7 +182,7 @@ def stage_roofline(wire_bytes: float, elapsed_s: Optional[float],
     p = max(1, int(parallelism))
     wire_dev = float(wire_bytes) / p
     hbm_total = 2.0 * float(wire_bytes) if hbm_bytes is None else float(hbm_bytes)
-    terms = roofline_terms(0.0, hbm_total / p, wire_dev)
+    terms = roofline_terms(0.0, hbm_total / p, wire_dev, device_kind)
     terms["wire_bytes"] = float(wire_bytes)
     terms["hbm_bytes"] = hbm_total
     terms["elapsed_s"] = float(elapsed_s) if elapsed_s is not None else None
@@ -188,7 +209,8 @@ def analyze(cell_result: Dict[str, Any], cfg, chips: int) -> Dict[str, Any]:
     # roofline fraction: useful FLOP rate at the step lower bound vs peak
     step = terms["step_s_lower_bound"]
     terms["roofline_fraction"] = (
-        mf / (step * chips * PEAK_FLOPS) if step > 0 else 0.0)
+        mf / (step * chips * device_peaks(V5E)["flops"])
+        if step > 0 else 0.0)
     return terms
 
 

@@ -14,7 +14,6 @@ import argparse
 import time
 
 import jax
-from .. import compat
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
@@ -100,7 +99,7 @@ def main() -> None:
 
     step_fn = jax.jit(make_train_step(cfg, opt_cfg, rules, ce_chunk=64))
     losses = []
-    with compat.set_mesh(mesh) if n_dev > 1 else _nullcontext():
+    with jax.set_mesh(mesh) if n_dev > 1 else _nullcontext():
         for step in range(start_step, args.steps):
             batch = {k: jnp.asarray(v) for k, v in next(batches).items()}
             t0 = time.time()

@@ -29,7 +29,6 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from .. import compat
 
 from .config import ModelConfig
 from .layers import (NO_SHARDING, Params, ShardingRules, constrain,
@@ -301,7 +300,7 @@ def moe_apply_shuffle(params: Params, x: jax.Array, cfg: ModelConfig,
         return y.reshape(b_l, s_l, d), aux[None]
 
     bspec = rules.batch
-    y, aux = compat.shard_map(
+    y, aux = jax.shard_map(
         body,
         in_specs=(P(bspec, axis, None), P(), P(axis, None, None),
                   P(axis, None, None), P(axis, None, None)),

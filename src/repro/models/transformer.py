@@ -32,7 +32,6 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from .. import compat
 
 from .. import flags
 from .attention import (gqa_attention, gqa_decode, gqa_init, gqa_specs,
@@ -335,7 +334,7 @@ def _vp_gather(table: jax.Array, toks: jax.Array,
                                     tiled=True)
 
     from jax.sharding import PartitionSpec as P
-    return compat.shard_map(
+    return jax.shard_map(
         local,
         in_specs=(P(rules.model, None), P(rules.batch, None)),
         out_specs=P(rules.batch, rules.model, None))(table, toks)

@@ -162,9 +162,15 @@ def render_analyze(pplan, stats, scan_rows: Optional[Dict[str, int]] = None,
     return "\n".join(lines)
 
 
-def stage_table(pplan, stats, parallelism: int) -> List[Dict[str, Any]]:
+def stage_table(pplan, stats, parallelism: int,
+                device: Any) -> List[Dict[str, Any]]:
     """Per-stage measured volumes + roofline terms (machine-readable rows;
-    ``QueryReport.roofline_table`` renders the markdown)."""
+    ``QueryReport.roofline_table`` renders the markdown).
+
+    The roofline terms are taken against the peaks of ``device`` (a
+    ``jax.Device``; ``launch.roofline.PEAKS``).  On the CPU backend there
+    is no chip to compare with, so those columns are ``None``; an
+    accelerator kind missing from the peaks table raises."""
     from ..launch.roofline import stage_roofline
     from ..planner.physical import node_stat_labels
     records = _records_by_label(stats)
@@ -183,19 +189,27 @@ def stage_table(pplan, stats, parallelism: int) -> List[Dict[str, Any]]:
                     wire += records[l]["bytes"]
                     srows += records[l]["rows"]
         secs = stage_secs.get(s)
-        terms = stage_roofline(wire, secs, parallelism)
-        rows.append({
+        row = {
             "stage": s,
             "ops": [n.op for n in by_stage[s]],
             "rows_shuffled": srows,
             "wire_bytes": wire,
             "elapsed_s": secs,
-            "memory_s": terms["memory_s"],
-            "collective_s": terms["collective_s"],
-            "bound_s": terms["step_s_lower_bound"],
-            "dominant": terms["dominant"],
-            "roofline_fraction": terms["roofline_fraction"],
-        })
+            "memory_s": None,
+            "collective_s": None,
+            "bound_s": None,
+            "dominant": None,
+            "roofline_fraction": None,
+        }
+        if device.platform != "cpu":
+            terms = stage_roofline(wire, secs, parallelism,
+                                   device.device_kind)
+            row.update(memory_s=terms["memory_s"],
+                       collective_s=terms["collective_s"],
+                       bound_s=terms["step_s_lower_bound"],
+                       dominant=terms["dominant"],
+                       roofline_fraction=terms["roofline_fraction"])
+        rows.append(row)
     return rows
 
 
@@ -210,13 +224,14 @@ class QueryReport:
     """
 
     def __init__(self, pplan, stats, trace: Optional[QueryTrace],
-                 parallelism: int,
+                 parallelism: int, device: Any,
                  scan_rows: Optional[Dict[str, int]] = None,
                  result_rows: Optional[int] = None):
         self.pplan = pplan
         self.stats = stats
         self.trace = trace
         self.parallelism = parallelism
+        self.device = device
         self.scan_rows = dict(scan_rows or {})
         self.result_rows = result_rows
 
@@ -229,7 +244,8 @@ class QueryReport:
                               self.result_rows)
 
     def stage_table(self) -> List[Dict[str, Any]]:
-        return stage_table(self.pplan, self.stats, self.parallelism)
+        return stage_table(self.pplan, self.stats, self.parallelism,
+                           self.device)
 
     def roofline_table(self) -> str:
         hdr = ("| stage | ops | rows | wire | elapsed s | bound s "
@@ -237,12 +253,16 @@ class QueryReport:
         lines = [hdr, "|" + "---|" * 8]
         for r in self.stage_table():
             el = f"{r['elapsed_s']:.4f}" if r["elapsed_s"] is not None else "-"
-            frac = (f"{r['roofline_fraction']:.3f}"
-                    if r["elapsed_s"] else "-")
+            if r["bound_s"] is None:
+                bound = dom = frac = "not measured"
+            else:
+                bound, dom = f"{r['bound_s']:.2e}", r["dominant"]
+                frac = (f"{r['roofline_fraction']:.3f}"
+                        if r["elapsed_s"] else "-")
             lines.append(
                 f"| {r['stage']} | {','.join(r['ops'])} "
                 f"| {r['rows_shuffled']} | {_fmt_bytes(r['wire_bytes'])} "
-                f"| {el} | {r['bound_s']:.2e} | {r['dominant']} | {frac} |")
+                f"| {el} | {bound} | {dom} | {frac} |")
         return "\n".join(lines)
 
     def to_chrome_trace(self, path: Optional[str] = None) -> Dict[str, Any]:
@@ -326,5 +346,6 @@ def run_analyzed(plan, env, tables: Dict[str, Any], mode: str = "bsp_staged",
     scan_rows = {name: r for name in pplan.scan_names
                  if (r := _rows_of(tables.get(name))) is not None}
     report = QueryReport(pplan, stats, qtrace, env.parallelism,
+                         env.devices[0],
                          scan_rows=scan_rows, result_rows=_rows_of(result))
     return result, report

@@ -180,14 +180,15 @@ def _as_spill(source: Any, parallelism: int) -> SpillTable:
     return respill(source, parallelism)
 
 
-def _to_dist(source: Any, parallelism: int) -> DistTable:
+def _to_dist(source: Any, env) -> DistTable:
     """Build-side inputs must be device-resident (they are assumed to fit)."""
     if isinstance(source, DistTable):
         return source
     from ..core.store import rescatter
     if isinstance(source, dict):
-        source = SpillTable.from_numpy(source, parallelism)
-    return rescatter(source, parallelism)  # handles any spill gang size
+        source = SpillTable.from_numpy(source, env.parallelism)
+    # handles any spill gang size
+    return rescatter(source, env.parallelism, mesh=env.mesh)
 
 
 def _schema_of(dist: DistTable) -> Dict[str, Tuple[np.dtype, Tuple[int, ...]]]:
@@ -495,7 +496,7 @@ def _build_resident(env, jnode: LogicalNode, tables, shuffle_impl,
                 stats.append((f"join({on}):right", _stat_vec(st, width)))
         return r, tuple(a for _, a in stats)
 
-    args = [_to_dist(tables[n], env.parallelism) for n in scan_names]
+    args = [_to_dist(tables[n], env) for n in scan_names]
     labels = plan_stat_labels(sub_order)
     if not elide:
         labels.append(f"join({on}):right")

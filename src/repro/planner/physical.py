@@ -781,7 +781,8 @@ def run_physical(pplan: PhysicalPlan, env, tables: Dict[str, Any],
             per = -(-max(s.total_rows(), 1) // env.parallelism)
             return _round8(2 * per)
         tables = {**tables, **{n: _rescatter(s, env.parallelism,
-                                             capacity=_cap(s))
+                                             capacity=_cap(s),
+                                             mesh=env.mesh)
                                for n, s in spills.items()}}
     root = pplan.root
     order = pplan.order
@@ -868,7 +869,8 @@ def run_physical(pplan: PhysicalPlan, env, tables: Dict[str, Any],
                 f"({describe_drops(stats.shuffle_records)}) and the plan "
                 f"cannot degrade to out-of-core execution ({e}); raise "
                 f"capacities or handle overflow='raise'") from e
-        out = attach_dictionaries(rescatter(spill, env.parallelism), root)
+        out = attach_dictionaries(
+            rescatter(spill, env.parallelism, mesh=env.mesh), root)
         d_stats.degraded += 1
         d_stats.retries += stats.retries
         d_stats.dispatches += stats.dispatches

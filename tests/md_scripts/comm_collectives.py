@@ -6,7 +6,6 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 
 import numpy as np
 import jax
-from repro import compat
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
@@ -24,7 +23,7 @@ for p in (6, 8):
 
         def body(xl):
             return getattr(comm, method)(xl[0])[None]
-        return jax.jit(compat.shard_map(
+        return jax.jit(jax.shard_map(
             body, mesh=mesh, in_specs=P("df"), out_specs=P("df"),
             check_vma=False))(x)
 
@@ -41,7 +40,7 @@ for p in (6, 8):
     for name in ("xla", "ring", "bruck"):
         comm = get_communicator(name, "df")
         for chunks in (1, 2, 3, 4):
-            got = jax.jit(compat.shard_map(
+            got = jax.jit(jax.shard_map(
                 lambda xl, c=comm, k=chunks: c.all_to_all_chunked(
                     xl[0], chunks=k)[None],
                 mesh=mesh, in_specs=P("df"), out_specs=P("df"),
@@ -50,7 +49,7 @@ for p in (6, 8):
     # broadcast + counts exchange
     for name in ("xla", "ring", "bruck"):
         comm = get_communicator(name, "df")
-        out = jax.jit(compat.shard_map(
+        out = jax.jit(jax.shard_map(
             lambda xl: comm.broadcast(xl[0], root=2)[None],
             mesh=mesh, in_specs=P("df"), out_specs=P("df"),
             check_vma=False))(x_flat)

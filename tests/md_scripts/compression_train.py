@@ -6,7 +6,6 @@ training loop vs full-precision DP: convergence within tolerance."""
 
 import numpy as np
 import jax
-from repro import compat
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
@@ -41,7 +40,7 @@ def make_step(compressed):
     def body(w, err, x, y):
         return step(w[0], err[0], x[0], y[0])
 
-    return jax.jit(compat.shard_map(
+    return jax.jit(jax.shard_map(
         lambda w, e, x, y: tuple(z[None] for z in body(w, e, x, y)),
         mesh=mesh,
         in_specs=(P("data"), P("data"), P("data"), P("data")),

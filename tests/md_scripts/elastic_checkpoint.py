@@ -8,7 +8,6 @@ import tempfile
 
 import numpy as np
 import jax
-from repro import compat
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -44,7 +43,7 @@ state = put(init_train_state(jax.random.PRNGKey(0), cfg, jnp.float32),
             mesh8, rules8)
 step8 = jax.jit(make_train_step(cfg, opt, rules8, ce_chunk=16))
 losses = []
-with compat.set_mesh(mesh8):
+with jax.set_mesh(mesh8):
     for _ in range(6):
         state, m = step8(state, mk_batch())
         losses.append(float(m["loss"]))
@@ -64,7 +63,7 @@ state4 = restore(f"{tmp}/ckpt_6", like, shardings4)
 assert int(state4["opt"]["step"]) == 6
 
 step4 = jax.jit(make_train_step(cfg, opt, rules4, ce_chunk=16))
-with compat.set_mesh(mesh4):
+with jax.set_mesh(mesh4):
     for _ in range(6):
         state4, m = step4(state4, mk_batch())
         losses.append(float(m["loss"]))

@@ -7,7 +7,6 @@ values, aux loss, and gradients."""
 
 import numpy as np
 import jax
-from repro import compat
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -27,7 +26,7 @@ rng = np.random.default_rng(0)
 params = moe_init(jax.random.PRNGKey(0), cfg, jnp.float32)
 x = jnp.asarray(rng.standard_normal((8, 32, 64)), jnp.float32)
 
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     def f_shuffle(p, xx):
         y, aux = moe_apply_shuffle(p, xx, cfg, rules)
         return y, aux

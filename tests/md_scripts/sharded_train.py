@@ -6,7 +6,6 @@ identical params + batch must give identical loss and matching updates."""
 
 import numpy as np
 import jax
-from repro import compat
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -41,7 +40,7 @@ for arch in ("llama3.2-3b", "olmoe-1b-7b", "mamba2-780m", "jamba-v0.1-52b",
     sharded = jax.tree_util.tree_map(
         lambda x, sp: jax.device_put(x, NamedSharding(mesh, sp)),
         state, specs, is_leaf=lambda x: isinstance(x, P))
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         s2, m2 = jax.jit(make_train_step(cfg, opt, rules, ce_chunk=16))(
             sharded, batch)
 

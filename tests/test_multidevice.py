@@ -20,6 +20,7 @@ def _run(name: str, timeout: int = 900) -> None:
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.abspath(SRC)
     env.pop("XLA_FLAGS", None)  # script sets its own
+    env["JAX_PLATFORMS"] = "cpu"  # 8 host devices; never the accelerator
     proc = subprocess.run(
         [sys.executable, os.path.join(SCRIPTS, name)],
         capture_output=True, text=True, timeout=timeout, env=env)

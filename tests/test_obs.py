@@ -337,6 +337,10 @@ def test_run_analyzed_report(rng, tmp_path):
                for r in stages if r["rows_shuffled"])
     md = report.roofline_table()
     assert md.splitlines()[0].startswith("| stage |")
+    # a CPU run has no chip to hold a roofline against
+    assert all(r["bound_s"] is None and r["roofline_fraction"] is None
+               for r in stages)
+    assert "not measured" in md
     d = json.loads(report.to_json())
     assert d["mode"] == "bsp_staged" and d["rows_dropped"] == 0
     assert d["fingerprint"] == report.pplan.fingerprint

@@ -1,9 +1,9 @@
 """Roofline machinery unit tests: HLO parsing + term math (no big compiles)."""
 
 import jax
-from repro import compat
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.launch import roofline
 
@@ -32,8 +32,8 @@ def test_wire_model():
 def test_parse_collectives_on_real_hlo():
     """Compile a tiny psum program on 1 device and parse its HLO."""
     mesh = jax.make_mesh((1,), ("x",))
-    with compat.set_mesh(mesh):
-        f = jax.jit(compat.shard_map(
+    with jax.set_mesh(mesh):
+        f = jax.jit(jax.shard_map(
             lambda x: jax.lax.psum(x, "x"),
             in_specs=jax.sharding.PartitionSpec("x"),
             out_specs=jax.sharding.PartitionSpec()))
@@ -51,6 +51,18 @@ def test_roofline_terms_dominance():
     t = roofline.roofline_terms(0.0, 819e9, 50e9 * 2)
     assert t["dominant"] == "collective"
     assert t["step_s_lower_bound"] == 2.0
+
+
+def test_peaks_keyed_by_device_kind():
+    assert roofline.device_peaks("TPU v5 lite")["hbm_bw"] == 819e9
+    with pytest.raises(ValueError, match="no published peaks"):
+        roofline.device_peaks("TPU v9 imaginary")
+    with pytest.raises(ValueError, match="no published peaks"):
+        roofline.stage_roofline(1e9, 1.0, 4, "cpu")
+    t = roofline.stage_roofline(8 * 819e9, 4.0, 4, "TPU v5 lite",
+                                hbm_bytes=4 * 819e9)
+    assert t["memory_s"] == 1.0 and t["collective_s"] == 2 * 819e9 / 50e9
+    assert t["roofline_fraction"] == t["step_s_lower_bound"] / 4.0
 
 
 def test_model_flops_conventions():
