@@ -40,6 +40,7 @@ import numpy as np
 
 from ..dataframe.ops_local import hash_columns_np
 from ..nulls import mask_name
+from ..obs.trace import NULL_TRACER
 from .config import AdaptiveConfig
 
 #: never salt from a sample smaller than this (frequencies too noisy)
@@ -231,18 +232,30 @@ def _round8(x: int) -> int:
 # ---------------------------------------------------------------------- #
 def plan_salt_decisions(order: Sequence[Any], tables: Mapping[str, Any],
                         p: int, cfg: AdaptiveConfig,
-                        events: Optional[List[Dict[str, Any]]] = None
-                        ) -> Dict[int, SaltDecision]:
+                        events: Optional[List[Dict[str, Any]]] = None,
+                        tracer=NULL_TRACER) -> Dict[int, SaltDecision]:
     """Detect skew at every salting candidate of a lowered plan.
 
     ``order`` is the plan's topo order; returns ``{nid: SaltDecision}``
     for the candidates where detection fired.  Purely driver-side: an
     empty result leaves execution (and every compile-cache key) exactly
-    as ``adaptive=False`` would."""
-    from ..planner.rules import skew_candidates
+    as ``adaptive=False`` would.  ``tracer`` records the whole of it as an
+    ``adapt:sample`` span with the key rows sampled (read back from the
+    device) and the decisions made."""
     out: Dict[int, SaltDecision] = {}
+    with tracer.span("adapt:sample", "adapt") as sp:
+        sampled = _plan_salt_decisions(order, tables, p, cfg, events, out)
+        sp.set(sampled_rows=sampled, salted=len(out))
+    return out
+
+
+def _plan_salt_decisions(order, tables, p, cfg, events, out) -> int:
+    """Fill ``out`` (see ``plan_salt_decisions``); returns the key rows
+    sampled."""
+    from ..planner.rules import skew_candidates
+    sampled = 0
     if p <= 1 or not (cfg.enabled and cfg.salting):
-        return out
+        return sampled
     index = {n.nid: i for i, n in enumerate(order)}
     for node in skew_candidates(order):
         keys = (list(node.params["keys"]) if node.op == "groupby"
@@ -253,8 +266,10 @@ def plan_salt_decisions(order: Sequence[Any], tables: Mapping[str, Any],
         src = tables.get(scan.params["name"])
         if src is None or _table_rows(src) < cfg.min_table_rows:
             continue
-        hot = detect_hot_keys(sample_key_columns(src, keys, cfg),
-                              keys, p, cfg)
+        sample = sample_key_columns(src, keys, cfg)
+        if sample is not None:
+            sampled += len(sample[keys[0]])
+        hot = detect_hot_keys(sample, keys, p, cfg)
         if not hot:
             continue
         if node.op == "groupby":
@@ -281,7 +296,7 @@ def plan_salt_decisions(order: Sequence[Any], tables: Mapping[str, Any],
                            "keys": list(d.keys),
                            "hot_keys": len(d.hot_hashes), "k": d.k,
                            "hot_cap": d.hot_cap})
-    return out
+    return sampled
 
 
 def salt_cache_token(salt: Mapping[int, SaltDecision],

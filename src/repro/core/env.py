@@ -18,8 +18,10 @@ its partition.
 from __future__ import annotations
 
 import dataclasses
+import hashlib
 import itertools
 import threading
+import weakref
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -33,6 +35,29 @@ from ..dataframe.table import Table
 from ..obs.trace import NULL_TRACER
 
 AXIS = "df"  # default dataframe axis name
+
+
+def _program_id(key: Any) -> str:
+    """A short, stable name for a program cache key (trace attrs)."""
+    return hashlib.sha1(repr(key).encode()).hexdigest()[:16]
+
+
+def _text_source(env: "CylonEnv", key: Any) -> Callable[[], Optional[str]]:
+    """``env.program_text(key)`` later, without keeping ``env`` alive."""
+    ref = weakref.ref(env)
+
+    def text() -> Optional[str]:
+        e = ref()
+        return e.program_text(key) if e is not None else None
+    return text
+
+
+def _spec_of(x: Any) -> Any:
+    """What lowering a program needs of one argument: its shape, dtype and
+    sharding, and never the array itself."""
+    if isinstance(x, jax.Array):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.sharding)
+    return x
 
 
 def put_rows(rows: np.ndarray,
@@ -289,6 +314,11 @@ class CylonEnv:
         #: counters.
         self.cache_hits = 0
         self.cache_misses = 0
+        #: per key: the boundary arguments' shapes, dtypes and shardings at
+        #: its first call here, and the program's HLO text once asked for
+        #: (``program_text``)
+        self._arg_specs: Dict[Any, Any] = {}
+        self._hlo_text: Dict[Any, str] = {}
 
     @property
     def parallelism(self) -> int:
@@ -299,6 +329,8 @@ class CylonEnv:
         persist for the next gang carved over these devices)."""
         with self._lock:
             self._cache.clear()
+            self._arg_specs.clear()
+            self._hlo_text.clear()
 
     # ------------------------------------------------------------------ #
     # Table conversion at the shard_map boundary
@@ -318,7 +350,7 @@ class CylonEnv:
     # Submission API (the paper's run_cylon / execute_cylon)
     # ------------------------------------------------------------------ #
     def run(self, fn: Callable, *args, static_kwargs: Optional[dict] = None,
-            key: Any = None):
+            key: Any = None, tracer=NULL_TRACER):
         """Run ``fn(ctx, *local_args, **static_kwargs)`` under shard_map.
 
         ``fn`` receives this env's communicator-bearing context and local
@@ -326,34 +358,65 @@ class CylonEnv:
         pytree of ``Table`` / arrays.  Returned Tables become ``DistTable``;
         returned arrays come back per-rank with a leading ``(p,)`` axis.
         Compiled programs are cached on the env (stateful reuse).
+
+        ``tracer`` records a ``dispatch`` span until the call returns, with
+        a ``compile`` span inside it on a cache miss (build and first,
+        tracing call), and registers the program's HLO text with the
+        tracer (``program_text``).
         """
         static_kwargs = static_kwargs or {}
         cache_key = key if key is not None else (
             fn, tuple(sorted(static_kwargs)),
             tuple(self._arg_sig(a) for a in args))
         boundary_args = tuple(self._to_boundary(a) for a in args)
-        with self._lock:
-            compiled = self._cache.get(cache_key)
-        if compiled is None:
-            # shared-cache path: single-flight build keyed by (program,
-            # gang placement).  A hit here — the program was compiled by an
-            # earlier env over the same devices, or by a racing thread —
-            # counts as a hit, so a freshly carved gang that reuses every
-            # program reports cache_misses == 0.
-            compiled, built = self.programs.get_or_build(
-                (cache_key, self._gang_key),
-                lambda: self._build(fn, args, static_kwargs))
+        program = _program_id(cache_key) if tracer else ""
+        with tracer.span("dispatch", "dispatch", program=program) as sp:
             with self._lock:
-                self._cache[cache_key] = compiled
-                if built:
-                    self.cache_misses += 1
-                else:
+                compiled = self._cache.get(cache_key)
+            sp.set(cache_hit=compiled is not None)
+            if compiled is None:
+                with self._lock:
+                    self._arg_specs[cache_key] = jax.tree_util.tree_map(
+                        _spec_of, boundary_args)
+                with tracer.span("compile", "compile", program=program):
+                    # shared-cache path: single-flight build keyed by
+                    # (program, gang placement).  A hit here — the program
+                    # was compiled by an earlier env over the same devices,
+                    # or by a racing thread — counts as a hit, so a freshly
+                    # carved gang that reuses every program reports
+                    # cache_misses == 0.
+                    compiled, built = self.programs.get_or_build(
+                        (cache_key, self._gang_key),
+                        lambda: self._build(fn, args, static_kwargs))
+                    with self._lock:
+                        self._cache[cache_key] = compiled
+                        if built:
+                            self.cache_misses += 1
+                        else:
+                            self.cache_hits += 1
+                    out_tree, caps = compiled(*boundary_args)
+            else:
+                with self._lock:
                     self.cache_hits += 1
-        else:
-            with self._lock:
-                self.cache_hits += 1
-        out_tree, caps = compiled(*boundary_args)
+                out_tree, caps = compiled(*boundary_args)
+            if tracer:
+                tracer.programs[program] = _text_source(self, cache_key)
         return self._from_boundary(out_tree, caps)
+
+    def program_text(self, key: Any) -> Optional[str]:
+        """The optimized HLO text of the program cached under ``key``,
+        lowered and compiled again from the argument shapes of its first
+        call (the compile caches make that cheap); None for a key this env
+        never built.  Memoized."""
+        with self._lock:
+            text = self._hlo_text.get(key)
+            compiled = self._cache.get(key)
+            specs = self._arg_specs.get(key)
+        if text is None and compiled is not None and specs is not None:
+            text = compiled.jitted.lower(*specs).compile().as_text()
+            with self._lock:
+                self._hlo_text[key] = text
+        return text
 
     def _arg_sig(self, a):
         if isinstance(a, DistTable):
@@ -394,7 +457,12 @@ class CylonEnv:
 
         treedef_box = {}
 
-        def shard_body(*bargs):
+        # The function's name is the XLA module's name, which prefixes
+        # JAX's persistent-cache key.  That key leaves out metadata, so
+        # without the name an executable compiled before the operator
+        # scopes (``planner.physical.eval_node``) existed would be loaded
+        # with op names that carry none.
+        def df_program(*bargs):
             treedef, converted = local_fn(*bargs)
             treedef_box["treedef"] = treedef
             return converted
@@ -404,7 +472,7 @@ class CylonEnv:
         # a single P(axis) applies to the whole output tree and no separate
         # structure-discovery trace is needed.
         mapped = jax.jit(jax.shard_map(
-            shard_body, mesh=self.mesh, in_specs=in_specs,
+            df_program, mesh=self.mesh, in_specs=in_specs,
             out_specs=P(self.axis), check_vma=False))
 
         # serialize the first invocation: tracing fills treedef_box, and
@@ -419,6 +487,7 @@ class CylonEnv:
             else:
                 out = mapped(*bargs)
             return (treedef_box["treedef"], out), None
+        runner.jitted = mapped
         return runner
 
     def _from_boundary(self, out_tree, caps):

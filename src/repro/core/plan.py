@@ -198,10 +198,12 @@ def execute(plan: Plan, env, tables: Dict[str, Any], mode: str = "bsp",
 
     ``trace`` turns on query tracing (``docs/observability.md``): ``True``
     builds a fresh ``repro.obs.Tracer``, an existing ``Tracer`` is used
-    as-is, and ``None`` consults the ``REPRO_TRACE`` env var.  The finished
-    ``QueryTrace`` is retrievable via ``repro.obs.last_trace()`` (or from
-    the tracer you passed).  Tracing is driver-side only — it never changes
-    what gets compiled.
+    as-is, and ``None`` consults the ``REPRO_TRACE`` env var, else traces
+    while a ``jax.profiler`` trace is recording.  The ``query`` span covers
+    planning too.  The finished ``QueryTrace`` is retrievable via
+    ``repro.obs.last_trace()`` (or from the tracer you passed), and from
+    ``ExecStats.trace`` with ``collect_stats=True``.  Tracing is
+    driver-side only — it never changes what gets compiled.
 
     Fault tolerance (``docs/fault_tolerance.md``): ``retries`` (int or
     ``repro.faults.RetryPolicy``) replays failed dispatch units with
@@ -219,10 +221,11 @@ def execute(plan: Plan, env, tables: Dict[str, Any], mode: str = "bsp",
     from ..obs.trace import resolve_tracer
     from ..planner import compile_plan, run_physical
     tracer = resolve_tracer(trace)
-    pplan = compile_plan(plan, tables, optimize_plan=optimize)
-    with tracer.span("query", "query", mode=mode,
-                     fingerprint=pplan.fingerprint,
-                     stages=pplan.num_stages, shuffles=pplan.num_shuffles):
+    with tracer.span("query", "query", mode=mode) as sp:
+        pplan = compile_plan(plan, tables, optimize_plan=optimize,
+                             tracer=tracer)
+        sp.set(fingerprint=pplan.fingerprint, stages=pplan.num_stages,
+               shuffles=pplan.num_shuffles)
         out = run_physical(pplan, env, tables, mode,
                            collect_stats=collect_stats,
                            shuffle_impl=shuffle_impl, a2a_chunks=a2a_chunks,
@@ -231,5 +234,7 @@ def execute(plan: Plan, env, tables: Dict[str, Any], mode: str = "bsp",
                            overflow=overflow, faults=faults,
                            adaptive=adaptive, **morsel_kw)
     if tracer.enabled:
-        tracer.finish()
+        qtrace = tracer.finish()
+        if collect_stats:
+            out[1].trace = qtrace
     return out

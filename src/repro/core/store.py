@@ -355,19 +355,15 @@ def respill_routed(spill: SpillTable, dest_of,
 # Bucketed rescatter (replaces the host-gather repartition)
 # ---------------------------------------------------------------------- #
 def rescatter(spill: SpillTable, parallelism: int,
-              capacity: Optional[int] = None,
-              tracer=NULL_TRACER, mesh=None) -> DistTable:
+              capacity: Optional[int] = None, mesh=None) -> DistTable:
     """SpillTable -> DistTable over a (possibly different) gang size,
     placed on ``mesh``'s devices when given (``core.env.put_rows``).
 
     Rows are routed chunk-by-chunk into per-destination host buckets by
     their global block index — no rank's data is ever concatenated into a
     single full-table host array, so peak extra host memory is one
-    destination shard, not the whole table.  ``tracer`` records the H2D
-    volume as an instant event.
+    destination shard, not the whole table.
     """
-    tracer.instant("rescatter", "transfer", to_p=parallelism,
-                   rows=spill.total_rows(), bytes=spill.nbytes())
     n = spill.total_rows()
     per = -(-max(n, 1) // parallelism)
     cap = capacity if capacity is not None else _round8(per)

@@ -150,6 +150,7 @@ def _overflow_warn(rank, send_dropped, recv_dropped, label=""):
             RuntimeWarning, stacklevel=2)
 
 
+@jax.named_scope("shuffle")
 def shuffle(
     table: Table,
     comm: Communicator,
@@ -173,6 +174,8 @@ def shuffle(
     pressure drops rows (they are always *counted* in the stats).
     ``label`` is a static plan-level tag (e.g. ``"join(k):left"``) used only
     to attribute overflow warnings — it never affects the computation.
+    The whole body runs under ``jax.named_scope("shuffle")``, metadata that
+    names its device ops (partition, all-to-all, compaction) in a profile.
     """
     if impl not in ("radix", "sorted"):
         raise ValueError(f"unknown shuffle impl {impl!r}")

@@ -10,10 +10,15 @@ Three layers (see ``docs/observability.md``):
                 future multi-query admission controller,
 * ``analyze`` — EXPLAIN ANALYZE (``QueryReport``): the EXPLAIN tree
                 re-rendered with *measured* per-node rows / bytes / times
-                plus a per-stage roofline table (``launch.roofline``).
+                plus a per-stage roofline table (``launch.roofline``),
+* ``hlo``     — the dataframe-operator scope of each HLO op of a compiled
+                program (``QueryTrace.op_scopes``), which names a profile's
+                device ops by operator.
 
-Tracing is opt-in (``trace=`` argument or ``REPRO_TRACE=1``) and purely
-driver-side: compiled programs are bit-identical with tracing on or off.
+Tracing is opt-in (``trace=`` argument, ``REPRO_TRACE=1``, or a recording
+``jax.profiler`` trace, whose host plane then carries every span as a
+``repro.<name>`` annotation) and purely driver-side: compiled programs are
+bit-identical with tracing on or off.
 
 ``analyze`` is imported lazily: it depends on ``repro.planner``, which
 itself imports this package's trace layer — eager import would cycle.

@@ -334,15 +334,17 @@ def run_analyzed(plan, env, tables: Dict[str, Any], mode: str = "bsp_staged",
     from ..planner import compile_plan, run_physical
     from .trace import resolve_tracer
     tracer = resolve_tracer(trace, name="analyze")
-    pplan = compile_plan(plan, tables, optimize_plan=optimize)
-    with tracer.span("query", "query", mode=mode,
-                     fingerprint=pplan.fingerprint,
-                     stages=pplan.num_stages, shuffles=pplan.num_shuffles):
+    with tracer.span("query", "query", mode=mode) as sp:
+        pplan = compile_plan(plan, tables, optimize_plan=optimize,
+                             tracer=tracer)
+        sp.set(fingerprint=pplan.fingerprint, stages=pplan.num_stages,
+               shuffles=pplan.num_shuffles)
         result, stats = run_physical(
             pplan, env, tables, mode, collect_stats=True,
             shuffle_impl=shuffle_impl, a2a_chunks=a2a_chunks,
             morsel_rows=morsel_rows, tracer=tracer, **morsel_kw)
     qtrace = tracer.finish() if isinstance(tracer, Tracer) else None
+    stats.trace = qtrace
     scan_rows = {name: r for name in pplan.scan_names
                  if (r := _rows_of(tables.get(name))) is not None}
     report = QueryReport(pplan, stats, qtrace, env.parallelism,

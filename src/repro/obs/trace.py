@@ -7,6 +7,13 @@ dispatches, never inside jit — enabling tracing cannot change what gets
 compiled (a test locks that compile-cache keys are identical with tracing
 on and off).
 
+The same spans go to a second sink: while a ``Tracer`` is enabled, each
+span also holds a ``jax.profiler.TraceAnnotation`` named ``repro.<span
+name>`` open for its lifetime, and each instant emits a zero-length one.
+Under a ``jax.profiler`` trace they land on the profile's host plane, on
+the clock of the device operations, where no harness phase or JAX event
+shares their prefix (``PROFILE_PREFIX``).  ``NULL_TRACER`` emits nothing.
+
 Timing convention: span end times are taken after the caller fences device
 work (``jax.block_until_ready`` on the dispatch outputs), so a stage span's
 duration covers dispatch + device execution, not just the Python submit.
@@ -24,11 +31,23 @@ from __future__ import annotations
 import dataclasses
 import itertools
 import json
+import os
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 _span_ids = itertools.count(1)
 _query_ids = itertools.count(1)
+
+#: the prefix of every profiler annotation a ``Tracer`` emits
+PROFILE_PREFIX = "repro."
+
+
+def _profile_args(attrs: Dict[str, Any]) -> Dict[str, Any]:
+    """Span attrs a profiler annotation can carry (its stats)."""
+    return {k: v for k, v in attrs.items()
+            if isinstance(v, (str, int, float, bool))}
 
 
 @dataclasses.dataclass
@@ -142,7 +161,11 @@ class Tracer:
         self._clock = clock
         self._spans: List[Span] = []
         self._stack: List[Span] = []
+        self._annotations: Dict[int, Any] = {}
         self._trace: Optional[QueryTrace] = None
+        #: program id -> callable returning that program's optimized HLO
+        #: text (``CylonEnv.run`` registers each program it dispatches)
+        self.programs: Dict[str, Callable[[], Optional[str]]] = {}
 
     def __bool__(self) -> bool:
         return True
@@ -153,8 +176,11 @@ class Tracer:
         driver-side call structure (the innermost open span is the parent).
         """
         parent = self._stack[-1].span_id if self._stack else None
+        ann = TraceAnnotation(PROFILE_PREFIX + name, **_profile_args(attrs))
+        ann.__enter__()
         s = Span(name, category, self._clock(), span_id=next(_span_ids),
                  parent_id=parent, attrs=dict(attrs))
+        self._annotations[s.span_id] = ann
         self._spans.append(s)
         self._stack.append(s)
         return _SpanHandle(self, s)
@@ -163,7 +189,8 @@ class Tracer:
         """Zero-duration marker under the currently open span (data-volume
         records for device-side ops whose timing the driver cannot see)."""
         parent = self._stack[-1].span_id if self._stack else None
-        t = self._clock()
+        with TraceAnnotation(PROFILE_PREFIX + name, **_profile_args(attrs)):
+            t = self._clock()
         s = Span(name, category, t, t, span_id=next(_span_ids),
                  parent_id=parent, attrs=dict(attrs), instant=True)
         self._spans.append(s)
@@ -171,6 +198,9 @@ class Tracer:
 
     def _end(self, span: Span) -> None:
         span.end_s = self._clock()
+        ann = self._annotations.pop(span.span_id, None)
+        if ann is not None:
+            ann.__exit__(None, None, None)
         # tolerate mis-nested exits instead of corrupting the stack
         if self._stack and self._stack[-1] is span:
             self._stack.pop()
@@ -184,7 +214,7 @@ class Tracer:
             self._end(self._stack[-1])
         if self._trace is None:
             self._trace = QueryTrace(self.name, self.query_id,
-                                     list(self._spans))
+                                     list(self._spans), dict(self.programs))
             _set_last_trace(self._trace)
         return self._trace
 
@@ -192,10 +222,16 @@ class Tracer:
 class QueryTrace:
     """Finished span tree for one query."""
 
-    def __init__(self, name: str, query_id: int, spans: List[Span]):
+    def __init__(self, name: str, query_id: int, spans: List[Span],
+                 programs: Optional[Dict[str, Callable[[], Optional[str]]]]
+                 = None):
         self.name = name
         self.query_id = query_id
         self.spans = spans
+        #: program id (a ``dispatch`` span's ``program`` attr) -> callable
+        #: returning the program's optimized HLO text, None once its env
+        #: is gone
+        self.programs = dict(programs or {})
 
     # -- structure ------------------------------------------------------- #
     def root(self) -> Optional[Span]:
@@ -217,6 +253,17 @@ class QueryTrace:
     def duration_s(self) -> float:
         r = self.root()
         return r.duration_s if r is not None else 0.0
+
+    def op_scopes(self) -> Dict[str, str]:
+        """HLO instruction name -> dataframe-operator scope (``join``,
+        ``shuffle``, ...; "" for none) over the programs this query
+        dispatched (``obs.hlo.op_scopes``).  Lowers and compiles each
+        program again, which the compile caches make cheap; call it
+        outside any timed region.  A name two programs scope differently
+        maps to ""."""
+        from .hlo import merge_scopes, op_scopes
+        return merge_scopes(op_scopes(text) for text in
+                            (f() for f in self.programs.values()) if text)
 
     # -- export ---------------------------------------------------------- #
     def to_dict(self) -> Dict[str, Any]:
@@ -287,14 +334,17 @@ def last_trace() -> Optional[QueryTrace]:
 def resolve_tracer(trace: Any, name: str = "query"):
     """Normalize the user-facing ``trace=`` argument.
 
-    ``None`` consults the ``REPRO_TRACE`` env var (opt-in flag; "0"/"" off);
-    ``False`` forces off; ``True`` builds a fresh ``Tracer``; a ``Tracer``
-    passes through.  Returns ``NULL_TRACER`` when disabled, so call sites
-    can use the handle unconditionally.
+    ``None`` consults the ``REPRO_TRACE`` env var ("1" on, "0" off) and,
+    when it is unset or empty, turns tracing on exactly while a
+    ``jax.profiler`` trace is being recorded, so that a profile of the
+    process shows the engine's spans; ``False`` forces off; ``True``
+    builds a fresh ``Tracer``; a ``Tracer`` passes through.  Returns
+    ``NULL_TRACER`` when disabled, so call sites can use the handle
+    unconditionally.
     """
-    import os
     if isinstance(trace, (Tracer, _NullTracer)):
         return trace
     if trace is None:
-        trace = os.environ.get("REPRO_TRACE", "") not in ("", "0")
+        flag = os.environ.get("REPRO_TRACE", "")
+        trace = flag != "0" if flag else TraceAnnotation.is_enabled()
     return Tracer(name) if trace else NULL_TRACER

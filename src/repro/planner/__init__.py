@@ -15,6 +15,7 @@ subsystem makes those boundaries an optimization target:
 ``compile_plan`` + ``run_physical`` directly for more control.
 """
 
+from ..obs.trace import NULL_TRACER
 from .logical import (COMM_OPS, LOCAL_OPS, LogicalNode, Partitioning,
                       annotate, build_catalog, copy_dag, from_plan, topo)
 from .rules import optimize
@@ -26,26 +27,32 @@ from .morsel import run_morsel
 from .explain import explain, render
 
 
-def compile_plan(plan, tables=None, optimize_plan: bool = True) -> PhysicalPlan:
+def compile_plan(plan, tables=None, optimize_plan: bool = True,
+                 tracer=NULL_TRACER) -> PhysicalPlan:
     """Builder tree (or LogicalNode) -> optimized, lowered PhysicalPlan.
 
     Dictionary resolution (``planner.dictionary``: recode insertion for
     mismatched join dictionaries, string-literal lowering, validation) runs
     unconditionally — it is a correctness pass, not an optimization.
+    ``tracer`` records the whole of it as a ``plan`` span.
     """
-    catalog = build_catalog(tables)
-    node = getattr(plan, "node", plan)
-    if isinstance(node, LogicalNode):
-        # copy: the rewrite passes below mutate in place, and the caller's
-        # DAG may be recompiled against different tables/dictionaries
-        root = annotate(copy_dag(node), catalog or None)
-    else:
-        root = from_plan(node, catalog)
-    fired = apply_dictionaries(root)
-    if optimize_plan:
-        root, opt_fired = optimize(root, catalog)
-        fired = fired + opt_fired
-    return lower(root, fired)
+    with tracer.span("plan", "plan") as sp:
+        catalog = build_catalog(tables)
+        node = getattr(plan, "node", plan)
+        if isinstance(node, LogicalNode):
+            # copy: the rewrite passes below mutate in place, and the
+            # caller's DAG may be recompiled against different
+            # tables/dictionaries
+            root = annotate(copy_dag(node), catalog or None)
+        else:
+            root = from_plan(node, catalog)
+        fired = apply_dictionaries(root)
+        if optimize_plan:
+            root, opt_fired = optimize(root, catalog)
+            fired = fired + opt_fired
+        pplan = lower(root, fired)
+        sp.set(fingerprint=pplan.fingerprint, stages=pplan.num_stages)
+    return pplan
 
 
 __all__ = [

@@ -138,6 +138,7 @@ def lower(root: LogicalNode, fired: Sequence[str] = ()) -> PhysicalPlan:
 # ---------------------------------------------------------------------- #
 # Shuffle implementations (direct vs the AMT object-store baseline)
 # ---------------------------------------------------------------------- #
+@jax.named_scope("shuffle")
 def shuffle_allgather(table: Table, comm: Communicator,
                       key_cols=None, dest=None, out_capacity=None, **_):
     """Every rank receives ALL rows and keeps those hashed to it.
@@ -332,6 +333,17 @@ def eval_node(node: LogicalNode, comm: Communicator,
               stats_out: Optional[List[Tuple[str, jax.Array]]] = None,
               shuffle_impl: str = "radix", a2a_chunks: int = 1,
               salt=None) -> Table:
+    """Evaluate one plan node inside the shard_map region, under
+    ``jax.named_scope(node.op)``: trace-time metadata that names the
+    node's device ops by operator (``repro.obs.hlo``) and changes nothing
+    that runs."""
+    with jax.named_scope(node.op):
+        return _eval_node(node, comm, values, tables, shuffle_mode,
+                          stats_out, shuffle_impl, a2a_chunks, salt)
+
+
+def _eval_node(node, comm, values, tables, shuffle_mode, stats_out,
+               shuffle_impl, a2a_chunks, salt) -> Table:
     p = node.params
     ins = [values[i.nid] for i in node.inputs]
     shuffle_fn = df_shuffle if shuffle_mode == "direct" else shuffle_allgather
@@ -609,6 +621,9 @@ class ExecStats:
     #: machine-readable trail EXPLAIN ANALYZE renders as annotations
     adapt_events: List[Dict[str, Any]] = \
         dataclasses.field(default_factory=list)
+    #: the finished ``repro.obs.QueryTrace`` when the execution was traced
+    trace: Optional[Any] = dataclasses.field(default=None, repr=False,
+                                             compare=False)
 
 
 def check_scan_dictionaries(order: Sequence[LogicalNode],
@@ -780,10 +795,12 @@ def run_physical(pplan: PhysicalPlan, env, tables: Dict[str, Any],
                 return scan_capacity
             per = -(-max(s.total_rows(), 1) // env.parallelism)
             return _round8(2 * per)
-        tables = {**tables, **{n: _rescatter(s, env.parallelism,
-                                             capacity=_cap(s),
-                                             mesh=env.mesh)
-                               for n, s in spills.items()}}
+        def _place(n, s):
+            with tr.span(f"place:{n}", "transfer", to_p=env.parallelism,
+                         rows=s.total_rows(), bytes=s.nbytes()):
+                return _rescatter(s, env.parallelism, capacity=_cap(s),
+                                  mesh=env.mesh)
+        tables = {**tables, **{n: _place(n, s) for n, s in spills.items()}}
     root = pplan.root
     order = pplan.order
     fp = pplan.fingerprint
@@ -798,7 +815,7 @@ def run_physical(pplan: PhysicalPlan, env, tables: Dict[str, Any],
     acfg = resolve_adaptive(adaptive)
     adapt_events: List[Dict[str, Any]] = []
     salt = (plan_salt_decisions(order, tables, env.parallelism, acfg,
-                                adapt_events)
+                                adapt_events, tracer=tr)
             if shuffle_mode == "direct" else {})
     eval_kw = dict(shuffle_impl=shuffle_impl, a2a_chunks=a2a_chunks,
                    salt=salt)
@@ -808,6 +825,12 @@ def run_physical(pplan: PhysicalPlan, env, tables: Dict[str, Any],
     t_query0 = time.perf_counter() if timing else 0.0
 
     def mk_stats(dispatches: int, pairs) -> ExecStats:
+        with tr.span("readback", "readback") as sp:
+            stats = _mk_stats(dispatches, pairs)
+            sp.set(rows_shuffled=stats.rows_shuffled)
+        return stats
+
+    def _mk_stats(dispatches: int, pairs) -> ExecStats:
         rows, byts, dropped = _sum_stats([pr[1] for pr in pairs])
         rows_read, bytes_read = scan_read_stats(names, tables)
         stats = ExecStats(mode, pplan.num_stages, pplan.num_shuffles,
@@ -903,7 +926,7 @@ def run_physical(pplan: PhysicalPlan, env, tables: Dict[str, Any],
                 return env.run(prog, *[tables[n] for n in names],
                                key=("bsp", fp, env.communicator_name,
                                     collect_stats, shuffle_impl, a2a_chunks)
-                                   + salt_cache_token(salt))
+                                   + salt_cache_token(salt), tracer=tr)
 
             res = run_with_retries(dispatch, policy=policy, token=token,
                                    tracer=tr, label="stage:program",
@@ -911,17 +934,20 @@ def run_physical(pplan: PhysicalPlan, env, tables: Dict[str, Any],
             sp.set(compiled=env.cache_misses > misses0)
             out = res[0] if collect_stats else res
             if timing:
-                jax.block_until_ready(
-                    (out.row_counts,) + (res[1] if collect_stats else ()))
+                with tr.span("wait", "wait"):
+                    jax.block_until_ready(
+                        (out.row_counts,) + (res[1] if collect_stats else ()))
                 stage_times.append(("program", time.perf_counter() - t0))
-            if collect_stats and tr.enabled:
-                emit_shuffle_events(
-                    tr, pair_stat_labels(plan_stat_labels(order, salt),
-                                         res[1]),
-                    a2a_chunks)
+            if collect_stats:
+                # read the counters back (in ``readback``) before the
+                # shuffle events reuse the host copies
+                pairs = pair_stat_labels(plan_stat_labels(order, salt),
+                                         res[1])
+                stats = mk_stats(1, pairs)
+                if tr.enabled:
+                    emit_shuffle_events(tr, pairs, a2a_chunks)
         if collect_stats:
-            pairs = pair_stat_labels(plan_stat_labels(order, salt), res[1])
-            return finish(attach_dictionaries(out, root), mk_stats(1, pairs))
+            return finish(attach_dictionaries(out, root), stats)
         return attach_dictionaries(out, root)
 
     if mode in ("bsp_staged", "amt"):
@@ -993,7 +1019,7 @@ def run_physical(pplan: PhysicalPlan, env, tables: Dict[str, Any],
                         _prog, *_args,
                         key=(mode, fp, _uidx, env.communicator_name,
                              collect_stats, shuffle_impl, a2a_chunks)
-                            + _usalt)
+                            + _usalt, tracer=tr)
 
                 res = run_with_retries(dispatch, policy=policy, token=token,
                                        tracer=tr, label=unit_names[uidx],
@@ -1007,12 +1033,13 @@ def run_physical(pplan: PhysicalPlan, env, tables: Dict[str, Any],
                 else:
                     out_tuple = res
                 dispatches += 1
-                for n, val in zip(outs, out_tuple):
-                    jax.block_until_ready(val.row_counts)  # completion barrier
-                    values[n.nid] = val
-                if timing:
-                    if collect_stats:
+                with tr.span("wait", "wait"):
+                    for n, val in zip(outs, out_tuple):
+                        jax.block_until_ready(val.row_counts)  # barrier
+                        values[n.nid] = val
+                    if timing and collect_stats:
                         jax.block_until_ready(unit_stats)
+                if timing:
                     stage_times.append(
                         (unit_names[uidx], time.perf_counter() - t0))
                 if collect_stats and tr.enabled:

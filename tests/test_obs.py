@@ -7,14 +7,20 @@ Unit scope (1 CPU device); the 8-device EXPLAIN ANALYZE golden scenario
 lives in ``tests/md_scripts/explain_analyze_fig9.py``.
 """
 
+import glob
 import json
+import os
+import re
 
+import jax
 import numpy as np
 import pytest
 
 from repro.core import CylonEnv, DistTable, Plan, execute
 from repro.obs import (METRICS, NULL_TRACER, MetricsRegistry, Tracer,
                        last_trace, record_exec, resolve_tracer, run_analyzed)
+from repro.obs.hlo import merge_scopes, op_scopes, scope_of
+from repro.obs.trace import PROFILE_PREFIX
 from repro.planner import compile_plan
 
 #: row width of the (int32 k, float32 v0) test tables — the independent
@@ -379,3 +385,173 @@ def test_df_collect_analyze(rng):
         assert "EXPLAIN ANALYZE" in text and "| stage |" in text
     finally:
         rdf.reset_default_env()
+
+
+# ---------------------------------------------------------------------- #
+# One clock: the engine's spans on the profiler's host plane
+# ---------------------------------------------------------------------- #
+def _fig9(rng, holder):
+    """The Fig-9 join -> groupby -> sort plan over two tables built by
+    ``holder(data, parallelism)``."""
+    env = CylonEnv()
+    rd = {"k": rng.integers(0, 12, 64).astype(np.int32),
+          "w": rng.integers(0, 64, 64).astype(np.float32)}
+    tables = {"l": holder(_data(rng, 128), env.parallelism),
+              "r": holder(rd, env.parallelism)}
+    plan = (Plan.scan("l").join(Plan.scan("r"), on="k", out_capacity=8192)
+            .groupby(["k"], {"v0": ["sum"]}).sort(["k"]))
+    return env, tables, plan
+
+
+def _engine_events(trace_dir):
+    """``(name, start_ns, end_ns)`` of the ``repro.`` events on the host
+    planes of the one profile under ``trace_dir``."""
+    from jax.profiler import ProfileData
+    (path,) = glob.glob(os.path.join(str(trace_dir), "**", "*.xplane.pb"),
+                        recursive=True)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name.startswith(PROFILE_PREFIX):
+                        s = int(ev.start_ns)
+                        out.append((ev.name, s, s + int(ev.duration_ns)))
+    return out
+
+
+@pytest.mark.parametrize("trace", [True, False], ids=["on", "off"])
+def test_spans_reach_the_profilers_host_plane_nested(rng, tmp_path, trace):
+    """Under ``jax.profiler``, a traced collect puts every span of its
+    tree on the host plane as ``repro.<name>``, each inside its parent's;
+    with tracing off it puts none there."""
+    from repro.core import SpillTable
+    env, tables, plan = _fig9(rng, SpillTable.from_numpy)
+    execute(plan, env, tables, collect_stats=True, trace=False)  # compiles
+    with jax.profiler.trace(str(tmp_path)):
+        _, st = execute(plan, env, tables, collect_stats=True, trace=trace)
+    events = _engine_events(tmp_path)
+    if not trace:
+        assert events == [] and st.trace is None
+        return
+    names = {n for n, _, _ in events}
+    assert {PROFILE_PREFIX + n for n in (
+        "query", "plan", "place:l", "place:r", "adapt:sample",
+        "stage:program", "dispatch", "wait", "readback")} <= names
+    assert PROFILE_PREFIX + "rescatter" not in names
+    at = {n: (s, e) for n, s, e in events}
+    spans = {s.span_id: s for s in st.trace.spans}
+    checked = 0
+    for s in st.trace.spans:
+        if s.parent_id is None or s.instant:
+            continue
+        (cs, ce), (ps, pe) = (at[PROFILE_PREFIX + s.name],
+                              at[PROFILE_PREFIX + spans[s.parent_id].name])
+        assert ps <= cs and ce <= pe, (s.name, spans[s.parent_id].name)
+        checked += 1
+    assert checked >= 8
+    parent = {s.name: spans[s.parent_id].name for s in st.trace.spans
+              if s.parent_id is not None}
+    assert parent["plan"] == parent["place:l"] == "query"
+    assert parent["dispatch"] == parent["wait"] == "stage:program"
+    assert st.trace.root().name == "query"
+    assert st.trace.find(name_prefix="dispatch")[0].attrs["cache_hit"]
+
+
+def test_resolve_tracer_follows_the_profiler(monkeypatch, tmp_path):
+    monkeypatch.delenv("REPRO_TRACE", raising=False)
+    with jax.profiler.trace(str(tmp_path)):
+        assert isinstance(resolve_tracer(None), Tracer)
+        assert resolve_tracer(False) is NULL_TRACER
+        monkeypatch.setenv("REPRO_TRACE", "0")
+        assert resolve_tracer(None) is NULL_TRACER
+    monkeypatch.delenv("REPRO_TRACE")
+    assert resolve_tracer(None) is NULL_TRACER
+
+
+# ---------------------------------------------------------------------- #
+# Operator scopes: named device ops, invisible to results and the cache
+# ---------------------------------------------------------------------- #
+@pytest.mark.parametrize("op_name, scope", [
+    ("jit(df_program)/join/jit(searchsorted)/while", "join"),
+    ("jit(df_program)/groupby/shuffle/all_to_all", "shuffle"),
+    ("jit(df_program)/sort/jit(sort)/sort", "sort"),
+    ("jit(df_program)/sort", ""),       # the primitive, not a scope
+    ("jit(df_program)/stack", ""),
+])
+def test_scope_of_an_op_name(op_name, scope):
+    assert scope_of(op_name) == scope
+
+
+def test_op_scopes_reads_fusions_through_their_computations():
+    text = "\n".join([
+        "HloModule m, entry_computation_layout={(f32[8]{0})->f32[8]{0}}",
+        "",
+        "%fused_computation (p.1: f32[8]) -> f32[8] {",
+        "  %p.1 = f32[8]{0} parameter(0)",
+        '  ROOT %add.2 = f32[8]{0} add(%p.1, %p.1), metadata={op_name='
+        '"jit(f)/groupby/add"}',
+        "}",
+        "",
+        "ENTRY %main.3 (x.1: f32[8]) -> f32[8] {",
+        "  %x.1 = f32[8]{0} parameter(0)",
+        '  %sort.4 = f32[8]{0} sort(%x.1), dimensions={0}, metadata={'
+        'op_name="jit(f)/join/sort" source_file="a.py" source_line=3}',
+        "  ROOT %fusion.5 = f32[8]{0} fusion(%sort.4), kind=kLoop, "
+        "calls=%fused_computation",
+        "}"])
+    scopes = op_scopes(text)
+    assert scopes["sort.4"] == "join"
+    assert scopes["fusion.5"] == "groupby"       # from its computation
+    assert scopes["x.1"] == ""
+    assert merge_scopes([{"a": "join"}, {"a": "sort", "b": ""}]) == \
+        {"a": "", "b": ""}
+
+
+def test_op_scopes_of_the_fig9_program(rng):
+    env, tables, plan = _fig9(rng, DistTable.from_numpy)
+    _, st = execute(plan, env, tables, collect_stats=True, trace=True)
+    (program,) = {s.attrs["program"] for s in st.trace.find("dispatch")}
+    assert set(st.trace.programs) == {program}
+    scopes = st.trace.op_scopes()
+    assert {"join", "groupby", "sort", "shuffle"} <= set(scopes.values())
+    assert env.program_text(next(iter(env._cache))).startswith("HloModule")
+
+
+def _instructions(hlo_text):
+    """The instructions of an HLO text with their metadata left out."""
+    return [re.sub(r", metadata=\{[^}]*\}", "", line)
+            for line in hlo_text.splitlines()
+            if re.match(r"\s*(ROOT\s+)?%|(ENTRY\s+)?%", line)]
+
+
+@pytest.mark.parametrize("mode", ["bsp", "bsp_staged", "amt"])
+def test_operator_scopes_invisible_to_results_and_cache(rng, monkeypatch,
+                                                        mode):
+    """The programs built with every operator and shuffle scope taken
+    away have the same cache keys, results and instructions: the scopes
+    are metadata and nothing else."""
+    import contextlib
+    import importlib
+    physical, groupby_mod, sort_mod = (importlib.import_module(m) for m in (
+        "repro.planner.physical", "repro.dataframe.groupby",
+        "repro.dataframe.sort"))
+    env, tables, plan = _fig9(rng, DistTable.from_numpy)
+    ref = execute(plan, env, tables, mode=mode).to_numpy()
+    texts = {k: env.program_text(k) for k in env._cache}
+
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    for mod, attr in [(physical, "df_shuffle"), (groupby_mod, "shuffle"),
+                      (sort_mod, "shuffle"), (physical, "shuffle_allgather")]:
+        monkeypatch.setattr(mod, attr, getattr(mod, attr).__wrapped__)
+    bare = CylonEnv()
+    out = execute(plan, bare, tables, mode=mode).to_numpy()
+    for c in ref:
+        np.testing.assert_array_equal(ref[c], out[c])
+    assert set(bare._cache) == set(texts)
+    for k, text in texts.items():
+        plain = bare.program_text(k)
+        assert _instructions(plain) == _instructions(text)
+        assert not set(op_scopes(plain).values()) - {""}
+    assert any(set(op_scopes(t).values()) - {""} for t in texts.values())
