@@ -320,14 +320,46 @@ def groupby_local(table: Table, keys: Sequence[str],
 # ---------------------------------------------------------------------- #
 
 
+def _merge_rank(sorted_arr: jax.Array, sorted_query: jax.Array,
+                sides: Sequence[str] = ("left", "right")) -> Tuple[jax.Array, ...]:
+    """``jnp.searchsorted(sorted_arr, sorted_query, side=s)`` for each
+    ``s`` in ``sides``, for a query that is itself sorted.
+
+    Both inputs sorted makes each rank a merge: one sort of the query
+    (tag 0, before equal keys of the array), the array (tag 1) and the
+    query again (tag 2, after them), and a running count of tag 1 then
+    reads, at a tag-0 entry, the keys below the query and, at a tag-2
+    entry, the keys at or below it.  A stable sort on the tag alone brings
+    the counts back into query order.  Two sorts and a cumsum, where a
+    binary search lowers to a ``while`` loop of full-width gathers.  The
+    order is ``lax.sort``'s, which ``jnp.searchsorted`` shares: NaN last,
+    signed zeros equal.
+    """
+    tag = {"left": 0, "right": 2}
+    blocks = [(sorted_arr, 1)] + [(sorted_query, tag[s]) for s in sides]
+    keys = jnp.concatenate([b for b, _ in blocks])
+    tags = jnp.concatenate([jnp.full(b.shape, t, jnp.int32) for b, t in blocks])
+    # (key, tag) order; entries equal in both carry equal ranks, so the
+    # merge needs no stability.
+    _, tags = jax.lax.sort((keys, tags), num_keys=2)
+    below = jnp.cumsum(tags == 1, dtype=jnp.int32)
+    _, below = jax.lax.sort((tags, below), num_keys=1, is_stable=True)
+    n = sorted_query.shape[0]
+    return tuple(below[:n] if s == "left" else below[below.shape[0] - n:]
+                 for s in sides)
+
+
 def join_local(left: Table, right: Table, on: str,
                out_capacity: Optional[int] = None,
                suffix: str = "_r", with_overflow: bool = False):
-    """Inner equi-join via sort + searchsorted (vectorized merge).
+    """Inner equi-join via sort + merge rank (vectorized merge).
 
-    Output capacity is static: ``out_capacity`` (default: left.capacity).
-    Row ``o`` of the output is derived by rank-searching the cumulative
-    match counts — O(cap log cap), no data-dependent shapes.
+    Both sides are sorted on ``on``; each left row's range of matches in
+    the right side, and the left row that owns each output slot (a rank of
+    the slot in the cumulative match counts), come from ``_merge_rank``:
+    sorts and a cumsum, O(cap log cap), no loop and no data-dependent
+    shapes.  Output capacity is static: ``out_capacity`` (default:
+    left.capacity).
 
     ``with_overflow=True`` additionally returns the number of result rows
     dropped by the static capacity (free here — the total match count is a
@@ -350,8 +382,7 @@ def join_local(left: Table, right: Table, on: str,
     rkey = jnp.where(rvalid, rkey_raw, _sentinel_for(rkey_raw.dtype))
 
     # For each left row: range of matches in right.
-    lo = jnp.searchsorted(rkey, lkey, side="left")
-    hi = jnp.searchsorted(rkey, lkey, side="right")
+    lo, hi = _merge_rank(rkey, lkey)
     hi = jnp.minimum(hi, right.row_count)  # sentinel rows never match
     counts = jnp.where(lvalid, jnp.maximum(hi - lo, 0), 0)
     cum = jnp.cumsum(counts)
@@ -359,7 +390,7 @@ def join_local(left: Table, right: Table, on: str,
 
     out_idx = jnp.arange(out_cap, dtype=jnp.int32)
     # left row owning output slot o: first l with cum[l] > o
-    l_row = jnp.searchsorted(cum, out_idx, side="right")
+    (l_row,) = _merge_rank(cum, out_idx, sides=("right",))
     l_row_c = jnp.minimum(l_row, left.capacity - 1)
     start = jnp.where(l_row_c > 0, cum[l_row_c - 1], 0)
     k = out_idx - start
@@ -385,14 +416,15 @@ def join_local(left: Table, right: Table, on: str,
 
 
 def join_overflow(left: Table, right: Table, on: str, out_capacity: int) -> jax.Array:
-    """Number of join result rows dropped by the static output capacity."""
+    """Number of join result rows dropped by the static output capacity:
+    both sides sorted, each left row's match count from ``_merge_rank``."""
     ls = sort_local(drop_null_keys(left, [on]), [on])
     rs = sort_local(drop_null_keys(right, [on]), [on])
     lvalid = ls.valid_mask()
     lkey = jnp.where(lvalid, ls.columns[on], _sentinel_for(ls.columns[on].dtype))
     rkey = jnp.where(rs.valid_mask(), rs.columns[on],
                      _sentinel_for(rs.columns[on].dtype))
-    lo = jnp.searchsorted(rkey, lkey, side="left")
-    hi = jnp.minimum(jnp.searchsorted(rkey, lkey, side="right"), rs.row_count)
+    lo, hi = _merge_rank(rkey, lkey)
+    hi = jnp.minimum(hi, rs.row_count)
     total = jnp.sum(jnp.where(lvalid, jnp.maximum(hi - lo, 0), 0))
     return jnp.maximum(total - out_capacity, 0)
