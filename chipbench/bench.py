@@ -5,9 +5,13 @@ those frames, fenced until its result is on the device.  Jobs run back to
 back from one client.  None starts once ``seconds`` have passed since the
 window opened.  The untraced run reports the cell's end-to-end metrics;
 the traced run records a profiler trace of a few whole jobs and reports
-the per-layer metrics read from it.  Either run compares with the pandas
+the per-layer metrics read from it.  Either run compares with the cell's
 reference, once the window has closed, a sample of the window's results
 drawn from the seed and its last result.
+
+The data, the job and the comparison come from the cell's own modules
+(``spec.Cell.recipe`` and ``spec.Cell.query``); nothing here names a
+table, a column or an operation.
 """
 
 from __future__ import annotations
@@ -22,8 +26,7 @@ from typing import Any, Dict, List, Optional
 import jax
 import numpy as np
 
-from . import query
-from .data import make_table_data, write_dataset
+from .data import write_dataset
 from .spec import Cell
 from .trace import read_trace
 
@@ -61,14 +64,18 @@ class CompileCounter:
         jax.monitoring.unregister_event_duration_listener(self._on)
 
 
-def make_tables(cell: Cell, seed: int, rows: int
+def read_tables(cell: Cell, seed: int, chips: int
                 ) -> Dict[str, Dict[str, np.ndarray]]:
-    """The cell's input tables of ``rows`` rows each, from ``seed``."""
-    data = cell.config["data"]
-    return {name: make_table_data(
-        rows, [seed % (1 << 63), i], cardinality=data["key_cardinality"],
-        value_max=data["value_max"])
-        for i, name in enumerate(cell.config["tables"])}
+    """The tables the cell's query reads, as its recipe makes them for
+    ``chips`` chips from ``seed``, in the order the query names them."""
+    names = cell.query.tables_read(cell.traffic["query"])
+    tables = cell.recipe.make_tables(cell.config, seed, chips, names)
+    return {name: tables[name] for name in names}
+
+
+def count_rows(tables: Dict[str, Dict[str, np.ndarray]]) -> int:
+    """Rows of all ``tables`` together: the input rows of one job."""
+    return sum(len(next(iter(t.values()))) for t in tables.values())
 
 
 class Workload:
@@ -77,23 +84,26 @@ class Workload:
     def __init__(self, cell: Cell, seed: int, devices, data_dir: str):
         import repro.df as rdf
         from repro.core import CylonEnv
-        cfg = cell.config
         self.env = CylonEnv(list(devices))
-        self.rows_per_rank = int(cfg["in_core_rows_per_chip"])
-        rows = self.rows_per_rank * len(devices)
-        self.ops = cell.traffic["query"]
-        self.tables = make_tables(cell, seed, rows)
-        self.input_rows = rows * len(self.tables)
+        self.cell = cell
+        self.tables = read_tables(cell, seed, len(devices))
+        self.input_rows = count_rows(self.tables)
+        files = int(cell.config["parquet_files_per_table"])
         self.frames = {}
         for name, t in self.tables.items():
-            glob = write_dataset(t, os.path.join(data_dir, name),
-                                 int(cfg["parquet_files_per_table"]))
+            glob = write_dataset(t, os.path.join(data_dir, name), files)
             self.frames[name] = rdf.read_parquet(glob, env=self.env,
                                                  name=name)
 
+    def frame(self):
+        """The job's lazy frame, built anew as a client would."""
+        cell = self.cell
+        return cell.query.build_frame(self.frames, cell.traffic["query"],
+                                      cell.config)
+
     def run_job(self):
         """One whole job; returns ``(result, ExecStats)``."""
-        q = query.build_frame(self.frames, self.ops, self.rows_per_rank)
+        q = self.frame()
         with jax.profiler.TraceAnnotation("collect"):
             res, stats = q.collect(env=self.env, mode="bsp",
                                    collect_stats=True)
@@ -171,10 +181,10 @@ def _run_cell(cell, seed, seconds, trace, devices, t_start, work_dir,
     with jax.profiler.TraceAnnotation("compare"):
         results = [r.to_numpy(nulls="mask") for r in kept]
         kept.clear()
-        ref = query.reference(wl.tables, wl.ops)
+        query, spec = cell.query, cell.traffic["query"]
+        ref = query.reference(wl.tables, spec)
         if control_dtype is not None:
-            ctl = query.as_result(
-                query.reference(wl.tables, wl.ops, control_dtype))
+            ctl = query.reference(wl.tables, spec, control_dtype)
             results = [ctl for _ in results]
         readings: Dict[str, float] = {}
         for got in results:

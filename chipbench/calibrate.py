@@ -36,8 +36,7 @@ def main(argv=None) -> None:
     args = ap.parse_args(argv)
     import ml_dtypes
 
-    from chipbench import query
-    from chipbench.bench import make_tables, run_cell
+    from chipbench.bench import read_tables, run_cell
     from chipbench.run import enable_compile_cache, require_chips
     from chipbench.spec import resolve
     cell = resolve(args.workload)
@@ -48,12 +47,11 @@ def main(argv=None) -> None:
         seed = args.seed0 + 7919 * i
         out = run_cell(cell, seed, args.seconds, False, devices,
                        time.perf_counter(), HERE)
-        ref_tables = make_tables(
-            cell, seed, cell.config["in_core_rows_per_chip"] * len(devices))
-        ref = query.reference(ref_tables, cell.traffic["query"])
-        ctl = query.reference(ref_tables, cell.traffic["query"],
-                              ml_dtypes.bfloat16)
-        c = query.compare(query.as_result(ctl), ref)
+        tables = read_tables(cell, seed, len(devices))
+        spec = cell.traffic["query"]
+        ref = cell.query.reference(tables, spec)
+        ctl = cell.query.reference(tables, spec, ml_dtypes.bfloat16)
+        c = cell.query.compare(ctl, ref)
         p = {k: v["value"] for k, v in out["checks"].items()}
         for k, v in p.items():
             program[k] = max(program.get(k, 0.0), v)

@@ -1,7 +1,9 @@
 """Device milliseconds per job in HLO ``while`` loops, body included: on
-this program the binary searches of ``jnp.searchsorted`` in the local join
-(``dataframe.ops_local.join_local``), one loop per search.  Summed per
-chip, averaged over chips, divided by the jobs traced."""
+this program the shuffle's radix partition (``kernels/radix_partition/
+xla.py``), whose blocked ``lax.scan`` runs where rows times buckets pass
+2**22, as on four chips; with one bucket on one chip it takes the dense
+path and no loop.  Summed per chip, averaged over chips, divided by the
+jobs traced."""
 
 
 def read(run):

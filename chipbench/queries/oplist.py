@@ -1,42 +1,56 @@
-"""A query as data, run two ways: through ``repro.df`` and through pandas.
+"""A query as a list of operations, run two ways: through ``repro.df`` and
+through pandas.
 
-A traffic file gives the query as a list of operations over named tables.
-``build_frame`` turns it into library calls; ``reference`` computes the
-same result with pandas alone, from the same host columns, and imports
-nothing of the program.  ``compare`` reduces one engine result against the
-reference to a few readings, each held to a limit by the harness.
+The spec (a traffic file's ``query``) names the table the operations start
+from and the operations, applied in order:
 
-Operations, applied in order to the first table (those of the paper's
-Fig-9 pipeline; a query that needs another adds it here):
+  {"module": "oplist", "table": <table>, "ops": [<op>, ...]}
 
   {"op": "merge", "right": <table>, "on": <col>, "out_capacity": <x>}
       inner equi-join; the engine's ``out_capacity`` is ``x`` times the
-      rows per rank of one input table
+      configuration's rows per chip (``in_core_rows_per_chip``)
+  {"op": "filter", "col": <col>, "ge": <number>}
+      keeps the rows where ``<col> >= <number>``
   {"op": "groupby_agg", "by": <col>, "aggs": {<col>: ["sum"]}}
-      output ``<col>_sum``
+      output ``<by>`` first, then ``<col>_sum``, one row per group
   {"op": "sort_values", "by": <col>}
   {"op": "add_scalar", "col": <col>, "value": <number>}
+
+``build_frame`` turns the spec into library calls; ``reference`` computes
+the same result with pandas alone, from the same host columns, and imports
+nothing of the program.  ``compare`` reduces one engine result against the
+reference to a few readings, each held to a limit by the harness.  It
+takes the result's first column as its key: one row per key, in the order
+of the reference, as a ``groupby_agg`` and a ``sort_values`` by its key
+leave it.
 """
 
 from __future__ import annotations
 
-from typing import Dict, Mapping, Sequence
+from typing import Dict, List, Mapping, Sequence
 
 import numpy as np
 import pandas as pd
 
 
-def build_frame(frames: Mapping[str, object], ops: Sequence[dict],
-                rows_per_rank: int):
-    """The library pipeline for ``ops`` over ``repro.df`` frames."""
+def tables_read(spec: dict) -> List[str]:
+    """The tables the query reads: its first, then each merge's right."""
+    return [spec["table"]] + [op["right"] for op in spec["ops"]
+                              if op["op"] == "merge"]
+
+
+def build_frame(frames: Mapping[str, object], spec: dict, config: dict):
+    """The library pipeline for ``spec`` over ``repro.df`` frames."""
     from repro.expr import Col
-    first = next(iter(frames))
-    df = frames[first]
-    for op in ops:
+    rows_per_rank = int(config["in_core_rows_per_chip"])
+    df = frames[spec["table"]]
+    for op in spec["ops"]:
         kind = op["op"]
         if kind == "merge":
             df = df.merge(frames[op["right"]], on=op["on"],
                           out_capacity=int(op["out_capacity"] * rows_per_rank))
+        elif kind == "filter":
+            df = df.filter(Col(op["col"]) >= op["ge"])
         elif kind == "groupby_agg":
             df = df.groupby(op["by"]).agg(dict(op["aggs"]))
         elif kind == "sort_values":
@@ -48,34 +62,33 @@ def build_frame(frames: Mapping[str, object], ops: Sequence[dict],
     return df
 
 
-def reference(tables: Mapping[str, Mapping[str, np.ndarray]],
-              ops: Sequence[dict], dtype=np.float32) -> pd.DataFrame:
-    """pandas result of ``ops``: one row per group, indexed by the group
-    key in ascending order, one column ``<col>_<agg>`` per aggregate.
+def reference(tables: Mapping[str, Mapping[str, np.ndarray]], spec: dict,
+              dtype=np.float32) -> Dict[str, np.ndarray]:
+    """pandas result of ``spec`` as host columns, in the engine's order.
 
     ``dtype`` is the precision of payload arithmetic.  ``np.float32`` is
     exact here (whole-number payloads, sums far below 2**24); a lower one,
     such as ``ml_dtypes.bfloat16``, accumulates every sum in that type and
     is the control that the comparison has to reject."""
-    first = next(iter(tables))
-    df = pd.DataFrame(dict(tables[first]))
-    out = None
-    for op in ops:
+    df = pd.DataFrame(dict(tables[spec["table"]]))
+    for op in spec["ops"]:
         kind = op["op"]
         if kind == "merge":
             df = df.merge(pd.DataFrame(dict(tables[op["right"]])),
                           on=op["on"], suffixes=("", "_r"))
+        elif kind == "filter":
+            df = df[df[op["col"]].to_numpy() >= op["ge"]]
         elif kind == "groupby_agg":
-            out = _aggregate(df, op["by"], op["aggs"], dtype)
+            df = _aggregate(df, op["by"], op["aggs"], dtype)
         elif kind == "sort_values":
-            out = out.sort_index(kind="stable")
+            df = df.sort_values(op["by"], kind="stable")
         elif kind == "add_scalar":
             col = op["col"]
-            out[col] = (out[col].to_numpy().astype(dtype)
-                        + np.asarray(op["value"], dtype)).astype(np.float64)
+            df[col] = (df[col].to_numpy().astype(dtype)
+                       + np.asarray(op["value"], dtype)).astype(np.float64)
         else:
             raise ValueError(f"unknown query op {kind!r}")
-    return out
+    return {c: df[c].to_numpy() for c in df.columns}
 
 
 def _aggregate(df: pd.DataFrame, by: str, aggs: Mapping[str, Sequence[str]],
@@ -87,7 +100,7 @@ def _aggregate(df: pd.DataFrame, by: str, aggs: Mapping[str, Sequence[str]],
     # round each result once, as pandas does; a lower type accumulates in
     # itself
     acc = np.float64 if dtype == np.float32 else dtype
-    cols = {}
+    cols = {by: keys[starts]}
     for col, names in aggs.items():
         v = df[col].to_numpy(np.float32).astype(acc)
         for agg in names:
@@ -95,26 +108,21 @@ def _aggregate(df: pd.DataFrame, by: str, aggs: Mapping[str, Sequence[str]],
                 raise ValueError(f"unknown aggregate {agg!r}")
             r = np.add.reduceat(v, starts)
             cols[f"{col}_{agg}"] = r.astype(dtype).astype(np.float64)
-    return pd.DataFrame(cols, index=pd.Index(keys[starts], name=by))
+    return pd.DataFrame(cols)
 
 
-def as_result(ref: pd.DataFrame) -> Dict[str, np.ndarray]:
-    """A reference frame in the shape of an engine result's host columns:
-    what the control hands to ``compare`` in the engine's place."""
-    return {ref.index.name: ref.index.to_numpy(),
-            **{c: ref[c].to_numpy() for c in ref.columns}}
-
-
-def compare(got: Mapping[str, np.ndarray], ref: pd.DataFrame
+def compare(got: Mapping[str, np.ndarray], ref: Mapping[str, np.ndarray]
             ) -> Dict[str, float]:
     """Readings of one engine result against the reference.
 
     ``key_mismatch``: groups missing, extra or out of order (0 when the
-    key column equals the reference's, position by position).  For each
-    value column, ``<col>_max_abs_err``.  Where the keys differ, value
-    readings are taken over the keys both sides have."""
-    keys = np.asarray(got[ref.index.name])
-    want = ref.index.to_numpy()
+    key column, the reference's first, equals the reference's, position
+    by position).  For each other column, ``<col>_max_abs_err``.  Where
+    the keys differ, value readings are taken over the keys both sides
+    have."""
+    key, *values = ref
+    keys = np.asarray(got[key])
+    want = np.asarray(ref[key])
     n = min(len(keys), len(want))
     mismatch = abs(len(keys) - len(want)) + int(np.sum(keys[:n] != want[:n]))
     out = {"key_mismatch": float(mismatch)}
@@ -122,12 +130,12 @@ def compare(got: Mapping[str, np.ndarray], ref: pd.DataFrame
         _, gi, ri = np.intersect1d(keys, want, return_indices=True)
     else:
         gi = ri = slice(None)
-    for col in ref.columns:
+    for col in values:
         name = f"{col}_max_abs_err"
         if col not in got:
             out[name] = float("inf")
             continue
         gap = (np.asarray(got[col], np.float64)[gi]
-               - ref[col].to_numpy(np.float64)[ri])
+               - np.asarray(ref[col], np.float64)[ri])
         out[name] = float(np.max(np.abs(gap), initial=0.0))
     return out

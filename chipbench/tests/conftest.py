@@ -22,9 +22,11 @@ def tiny_cell(name: str, rows: int = TINY_ROWS):
 
 @pytest.fixture
 def run_tiny(tmp_path):
-    """Run a cell at TINY_ROWS on this process's first devices."""
-    def run(name, trace=False, seconds=0.2, **kw):
-        cell = tiny_cell(name)
+    """Run a cell, by name at TINY_ROWS or as given, on this process's
+    first devices."""
+    def run(cell, trace=False, seconds=0.2, **kw):
+        if isinstance(cell, str):
+            cell = tiny_cell(cell)
         return run_cell(cell, SEED, seconds, trace, jax.devices()[:cell.chips],
                         time.perf_counter(), str(tmp_path), **kw)
     return run

@@ -17,7 +17,6 @@ sys.path[:0] = [os.path.join(os.path.dirname(__file__), *up)
 
 import jax  # noqa: E402
 
-from chipbench import query  # noqa: E402
 from chipbench.bench import Workload  # noqa: E402
 from chipbench.program import op_scopes  # noqa: E402
 from chipbench.spec import load_reader  # noqa: E402
@@ -39,7 +38,7 @@ def main():
         wl = Workload(tiny_cell("fig9-4chip.incore"), SEED, devices, work)
         stats = []
         for _ in range(2):
-            q = query.build_frame(wl.frames, wl.ops, wl.rows_per_rank)
+            q = wl.frame()
             res, st = q.collect(env=wl.env, mode="bsp", collect_stats=True,
                                 trace=True)
             jax.block_until_ready((res.columns, res.row_counts))

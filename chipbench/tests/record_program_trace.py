@@ -39,7 +39,6 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
 import jax  # noqa: E402
 
-from chipbench import query  # noqa: E402
 from chipbench.bench import Workload  # noqa: E402
 from chipbench.program import (SpanRun, load_engine_spans,  # noqa: E402
                                op_scopes, scope_seconds, scopes_path)
@@ -54,7 +53,7 @@ def rows_per_s(wl: Workload, seconds: float, trace: bool) -> float:
     with the engine's tracing ``trace``."""
     jobs, t0 = 0, time.perf_counter()
     while time.perf_counter() - t0 < seconds:
-        q = query.build_frame(wl.frames, wl.ops, wl.rows_per_rank)
+        q = wl.frame()
         res, _ = q.collect(env=wl.env, mode="bsp", collect_stats=True,
                            trace=trace)
         jax.block_until_ready((res.columns, res.row_counts))

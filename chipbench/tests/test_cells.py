@@ -8,7 +8,9 @@ import sys
 
 import pytest
 
+from chipbench.bench import read_tables
 from chipbench.spec import HERE, ROOT, load_benchmark, resolve
+from chipbench.tests.conftest import SEED
 
 BENCH = load_benchmark()
 CELLS = [w["name"] for w in BENCH["workloads"]]
@@ -23,9 +25,16 @@ def test_cell_resolves_by_name(name):
                                        f"{w['traffic']}.json"))
     assert {m.name for m in cell.end_to_end} >= {"rows_per_s", "setup_s"}
     assert cell.per_layer and all(callable(m.read) for m in cell.per_layer)
-    for op in cell.traffic["query"]:
-        assert op["op"] in ("merge", "groupby_agg", "sort_values",
-                            "add_scalar")
+    # every table the query reads is one the recipe makes, and the cell's
+    # own query module knows every part of its spec: its reference runs,
+    # and every reading it compares has a limit
+    cell.config["in_core_rows_per_chip"] = 64
+    spec = cell.traffic["query"]
+    tables = read_tables(cell, SEED, cell.chips)
+    assert list(tables) == cell.query.tables_read(spec)
+    ref = cell.query.reference(tables, spec)
+    assert ref and set(cell.traffic["limits"]) >= set(
+        cell.query.compare(ref, ref))
 
 
 @pytest.mark.parametrize("cfg", BENCH["configs"], ids=lambda c: c["name"])
@@ -37,8 +46,9 @@ def test_config_file_states_its_cut(cfg):
     assert len(data["source"]) <= 200
     assert set(cfg["reduced"]) == set(data["reduced"])
     assert all(k in data for k in cfg["reduced"])
-    assert {"key_cardinality", "out_capacity",
-            "parquet_files_per_table"} <= set(data["assumed"])
+    assert os.path.exists(os.path.join(HERE, "recipes",
+                                       f"{data['recipe']}.py"))
+    assert data["assumed"]
     assert data["guarantee"].startswith("exact")
 
 
