@@ -82,8 +82,10 @@ class DistTable:
 
     ``dictionaries`` maps each dictionary-encoded string column to its
     sorted dictionary (``dataframe.schema``); the device columns for those
-    names hold int32 codes.  Purely driver-side metadata — it never enters
-    the compiled programs.
+    names hold int32 codes.  ``dates`` names the date columns, held as
+    int32 days since 1970-01-01 and returned as ``datetime64[D]`` by
+    ``to_numpy``.  Purely driver-side metadata — neither enters the
+    compiled programs.
     """
 
     columns: Dict[str, jax.Array]
@@ -95,6 +97,7 @@ class DistTable:
     #: (files, rows, source bytes); None for tables built in memory.
     #: Driver-side only — EXPLAIN ANALYZE attributes scan work from it.
     provenance: Optional[Any] = None
+    dates: Tuple[str, ...] = ()
 
     @property
     def parallelism(self) -> int:
@@ -113,15 +116,17 @@ class DistTable:
 
         String columns (object / unicode numpy arrays) are dictionary-
         encoded host-side: the device gets int32 codes, the sorted
-        dictionary lands in ``dictionaries``.  NaN / ``None`` values (or
+        dictionary lands in ``dictionaries``.  ``datetime64`` columns hold
+        whole dates and become int32 days (named in ``dates``).  NaN / ``None`` values (or
         explicit ``__m_*`` companions) become validity-mask columns with
         canonical-zero data slots (``repro.nulls``).  An explicit
         ``capacity`` — including ``0`` — is honored verbatim and validated
         against the per-shard row count."""
-        from ..dataframe.schema import encode_columns
+        from ..dataframe.schema import encode_columns, encode_dates
         from ..nulls import extract_null_columns
         data = extract_null_columns(
             {k: np.asarray(v) for k, v in data.items()})
+        data, dates = encode_dates(data)
         data, dicts = encode_columns(data)
         n = len(next(iter(data.values())))
         per = -(-n // parallelism)
@@ -140,14 +145,16 @@ class DistTable:
                 counts[r] = len(chunk)
             cols[name] = put_rows(
                 buf.reshape((parallelism * capacity,) + arr.shape[1:]), mesh)
-        return cls(cols, put_rows(counts, mesh), capacity, dicts)
+        return cls(cols, put_rows(counts, mesh), capacity, dicts,
+                   dates=dates)
 
     def to_numpy(self, decode: bool = True, nulls: str = "pandas"
                  ) -> Dict[str, np.ndarray]:
         """Gather valid rows from every shard (driver side, not jitted).
 
         ``decode=True`` (default) maps dictionary-encoded columns back to
-        numpy string arrays; ``decode=False`` returns the raw int32 codes.
+        numpy string arrays and date columns to ``datetime64[D]``;
+        ``decode=False`` returns the raw int32 codes and days.
         ``nulls="pandas"`` (default) re-materializes validity masks as
         NaN / ``None`` (consuming the ``__m_*`` columns);
         ``nulls="mask"`` returns the raw physical layout — canonical-zero
@@ -161,9 +168,10 @@ class DistTable:
         for name, arr in self.columns.items():
             a = np.asarray(arr).reshape((p, cap) + arr.shape[1:])
             out[name] = np.concatenate([a[r, :counts[r]] for r in range(p)], axis=0)
-        if decode and self.dictionaries:
-            from ..dataframe.schema import decode_columns
-            out = decode_columns(out, self.dictionaries)
+        if decode:
+            from ..dataframe.schema import decode_columns, decode_dates
+            out = decode_dates(decode_columns(out, self.dictionaries),
+                               self.dates)
         if nulls == "pandas":
             from ..nulls import apply_null_columns
             out = apply_null_columns(out)
@@ -247,7 +255,8 @@ class MorselSource:
         self._tracer.instant(f"h2d:morsel[{m}]", "transfer", morsel=m,
                              bytes=self.h2d_bytes - b0)
         return DistTable(cols, put_rows(counts, self._mesh), cap,
-                         dict(self.spill.dictionaries))
+                         dict(self.spill.dictionaries),
+                         dates=self.spill.dates)
 
     def __iter__(self):
         nxt = self._build(0)

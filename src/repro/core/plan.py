@@ -134,7 +134,25 @@ class Plan:
                          {"keys": tuple(keys), "aggs": dict(aggs), **kw}))
 
     def sort(self, by: Sequence[str], **kw) -> "Plan":
+        """Global sort by ``by``; ``ascending=`` (one flag per key) sorts
+        some keys descending.  All-ascending flags are dropped, so such a
+        plan is the plain ascending one."""
+        asc = kw.pop("ascending", None)
+        if asc is not None:
+            asc = tuple(bool(a) for a in asc)
+            if len(asc) != len(by):
+                raise ValueError(f"ascending has {len(asc)} flags for "
+                                 f"{len(by)} sort keys")
+            if not all(asc):
+                kw["ascending"] = asc
         return Plan(Node("sort", [self.node], {"by": tuple(by), **kw}))
+
+    def limit(self, n: int) -> "Plan":
+        """The first ``n`` rows in global order (after a sort: its top
+        ``n``)."""
+        if int(n) != n or n < 0:
+            raise ValueError(f"limit takes a whole number >= 0, got {n!r}")
+        return Plan(Node("limit", [self.node], {"n": int(n)}))
 
     def shuffle(self, key_cols: Sequence[str], **kw) -> "Plan":
         return Plan(Node("shuffle", [self.node], {"key_cols": tuple(key_cols), **kw}))

@@ -24,7 +24,7 @@ from __future__ import annotations
 
 import threading
 import time
-from typing import Dict, List, Mapping, Optional, Tuple, Union
+from typing import Dict, List, Mapping, Optional, Sequence, Tuple, Union
 
 import jax
 import numpy as np
@@ -54,17 +54,21 @@ class SpillTable:
     columns and dtypes.  ``dictionaries`` carries the sorted per-column
     dictionaries of string columns (chunks hold int32 codes), exactly like
     ``DistTable.dictionaries``; spill/respill/rescatter preserve it.
+    ``dates`` names the date columns (int32 days since 1970-01-01, like
+    ``DistTable.dates``), preserved the same way.
     """
 
     def __init__(self, parallelism: int,
                  schema: Optional[Mapping[str, Tuple[np.dtype, Tuple[int, ...]]]]
                  = None,
-                 dictionaries: Optional[Mapping[str, Tuple[str, ...]]] = None):
+                 dictionaries: Optional[Mapping[str, Tuple[str, ...]]] = None,
+                 dates: Sequence[str] = ()):
         if parallelism < 1:
             raise ValueError(f"parallelism must be >= 1, got {parallelism}")
         self.parallelism = parallelism
         self.dictionaries: Dict[str, Tuple[str, ...]] = \
             dict(dictionaries or {})
+        self.dates: Tuple[str, ...] = tuple(sorted(dates))
         #: ``repro.io.IngestInfo`` when read from Parquet/CSV, else None
         self.provenance = None
         self._chunks: List[List[Dict[str, np.ndarray]]] = \
@@ -149,9 +153,10 @@ class SpillTable:
             return {}
         out = {k: np.concatenate([p[k] for p in parts], axis=0)
                for k in names}
-        if decode and self.dictionaries:
-            from ..dataframe.schema import decode_columns
-            out = decode_columns(out, self.dictionaries)
+        if decode:
+            from ..dataframe.schema import decode_columns, decode_dates
+            out = decode_dates(decode_columns(out, self.dictionaries),
+                               self.dates)
         if nulls == "pandas":
             from ..nulls import apply_null_columns
             out = apply_null_columns(out)
@@ -168,19 +173,21 @@ class SpillTable:
                    chunk_rows: Optional[int] = None) -> "SpillTable":
         """Block-distribute host rows over ``parallelism`` rank buckets,
         optionally pre-chunked into ``chunk_rows``-row pieces.  String
-        columns are dictionary-encoded (chunks hold int32 codes)."""
-        from ..dataframe.schema import encode_columns
+        columns are dictionary-encoded (chunks hold int32 codes), date
+        columns held as int32 days."""
+        from ..dataframe.schema import encode_columns, encode_dates
         from ..nulls import extract_null_columns
         data = {k: np.asarray(v) for k, v in data.items()}
         if not data:
             raise ValueError("need at least one column")
         data = extract_null_columns(data)
+        data, dates = encode_dates(data)
         data, dicts = encode_columns(data)
         n = len(next(iter(data.values())))
         per = -(-n // parallelism) if n else 0
         out = cls(parallelism,
                   schema={k: (v.dtype, v.shape[1:]) for k, v in data.items()},
-                  dictionaries=dicts)
+                  dictionaries=dicts, dates=dates)
         for r in range(parallelism):
             block = {k: v[r * per:(r + 1) * per] for k, v in data.items()}
             rows = len(next(iter(block.values())))
@@ -198,7 +205,7 @@ class SpillTable:
                 for k, v in table.columns.items()}
         out = cls(p, schema={k: (v.dtype, v.shape[2:])
                              for k, v in host.items()},
-                  dictionaries=table.dictionaries)
+                  dictionaries=table.dictionaries, dates=table.dates)
         out.provenance = table.provenance
         for r in range(p):
             c = int(counts[r])
@@ -314,7 +321,7 @@ def respill(spill: SpillTable, parallelism: int,
                      to_p=parallelism, rows=spill.total_rows(),
                      bytes=spill.nbytes()):
         out = SpillTable(parallelism, schema=spill.schema or None,
-                         dictionaries=spill.dictionaries)
+                         dictionaries=spill.dictionaries, dates=spill.dates)
         out.provenance = spill.provenance
         for dest, pieces in enumerate(_route_chunks(spill, parallelism)):
             for piece in pieces:
@@ -337,7 +344,7 @@ def respill_routed(spill: SpillTable, dest_of,
     with tracer.span("respill-routed", "spill", p=spill.parallelism,
                      rows=spill.total_rows(), bytes=spill.nbytes()):
         out = SpillTable(spill.parallelism, schema=spill.schema or None,
-                         dictionaries=spill.dictionaries)
+                         dictionaries=spill.dictionaries, dates=spill.dates)
         out.provenance = spill.provenance
         for r in range(spill.parallelism):
             for chunk in spill.rank_chunks(r):
@@ -386,7 +393,7 @@ def rescatter(spill: SpillTable, parallelism: int,
                               mesh)
     return DistTable(cols, put_rows(counts, mesh), cap,
                      dict(spill.dictionaries),
-                     provenance=spill.provenance)
+                     provenance=spill.provenance, dates=spill.dates)
 
 
 def repartition(table: Union[DistTable, SpillTable], parallelism: int,

@@ -88,18 +88,35 @@ def hash_columns_np(columns, key_cols: Sequence[str]) -> np.ndarray:
 # ---------------------------------------------------------------------- #
 
 
-def _order_keys(table: Table, by: Sequence[str]) -> Tuple[jax.Array, ...]:
+def descending_key(v: jax.Array) -> jax.Array:
+    """An order-reversing image of ``v``: sorting it ascending sorts ``v``
+    descending.  Floats negate (the sort canonicalizes NaN, so NaN stays
+    last, and -0.0 to 0.0); integers and booleans take the bitwise
+    complement, which reverses their order and cannot overflow."""
+    if jnp.issubdtype(v.dtype, jnp.floating):
+        return jnp.negative(v)
+    return jnp.invert(v)
+
+
+def _order_keys(table: Table, by: Sequence[str],
+                ascending: Optional[Sequence[bool]] = None
+                ) -> Tuple[jax.Array, ...]:
     """Key arrays for lexsort, with padding rows forced to sort last.
 
     Nullable sort columns contribute a null flag *more major* than their
     value key, so nulls sort last within each column (pandas
-    ``na_position="last"``); ties among nulls resolve stably because null
-    slots hold the canonical zero."""
+    ``na_position="last"``) in either direction; ties among nulls resolve
+    stably because null slots hold the canonical zero.  A column whose
+    ``ascending`` flag is False sorts by its ``descending_key``; with no
+    flags (or all True) the keys are exactly the ascending ones."""
     valid = table.valid_mask()
     keys = []
     # jnp.lexsort sorts by the LAST key first; build minor -> major.
-    for name in reversed(by):
+    for i in reversed(range(len(by))):
+        name = by[i]
         v = table.columns[name]
+        if ascending is not None and not ascending[i]:
+            v = descending_key(v)
         keys.append(jnp.where(valid, v, _sentinel_for(v.dtype)))
         m = table.columns.get(mask_name(name))
         if m is not None:
@@ -107,9 +124,12 @@ def _order_keys(table: Table, by: Sequence[str]) -> Tuple[jax.Array, ...]:
     return tuple(keys) + (jnp.where(valid, 0, 1).astype(jnp.int32),)
 
 
-def sort_local(table: Table, by: Sequence[str]) -> Table:
-    """Stable multi-key sort of the valid prefix (padding stays at the end)."""
-    keys = _order_keys(table, by)
+def sort_local(table: Table, by: Sequence[str],
+               ascending: Optional[Sequence[bool]] = None) -> Table:
+    """Stable multi-key sort of the valid prefix (padding stays at the
+    end); ``ascending`` gives each key's direction (default: all
+    ascending)."""
+    keys = _order_keys(table, by, ascending)
     # validity flag is the most-major key so padding sorts last.
     order = jnp.lexsort(keys[:-1] + (keys[-1],))
     return table.take(order, table.row_count)

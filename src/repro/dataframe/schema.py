@@ -30,6 +30,12 @@ lives entirely on the driver:
 * ``expr_dictionary``   — which dictionary (if any) an expression's output
                           codes belong to.
 
+Date columns follow the same pattern with no dictionary: the device holds
+int32 days since 1970-01-01 (``encode_dates`` / ``decode_dates``), which
+are order-isomorphic to the dates, and the holders and plan nodes name the
+date columns (``DistTable.dates``, ``LogicalNode.dates``).  ``lower_expr``
+turns a date literal compared with a date column into an int32 day number.
+
 Dictionaries are plain tuples of python str, carried by the driver-side
 table holders (``core.DistTable.dictionaries`` /
 ``core.SpillTable.dictionaries``) and by every annotated logical plan node
@@ -50,6 +56,8 @@ __all__ = [
     "Dictionary", "is_string_array", "encode_strings", "decode_codes",
     "encode_columns", "decode_columns", "merge_dictionaries",
     "recode_mapping", "lower_expr", "expr_dictionary", "DictTypeError",
+    "DATE_DTYPE", "encode_dates", "decode_dates", "date_days",
+    "expr_is_date",
 ]
 
 #: a column dictionary: lexicographically sorted, duplicate-free strings
@@ -57,6 +65,8 @@ Dictionary = Tuple[str, ...]
 
 #: device dtype of dictionary codes
 CODE_DTYPE = np.int32
+#: device dtype of date columns: days since 1970-01-01
+DATE_DTYPE = np.int32
 
 
 class DictTypeError(TypeError):
@@ -147,6 +157,35 @@ def encode_columns(data: Mapping[str, np.ndarray]
     return cols, dicts
 
 
+def date_days(arr, name: str = "column") -> np.ndarray:
+    """``datetime64`` values -> int32 days since 1970-01-01.  A value with
+    a time of day is refused rather than truncated."""
+    arr = np.asarray(arr)
+    days = arr.astype("datetime64[D]")
+    if np.any((days != arr) & ~np.isnat(arr)):
+        raise TypeError(f"{name} holds times of day; only whole dates "
+                        f"(datetime64[D]) are supported")
+    return days.astype(np.int64).astype(DATE_DTYPE)
+
+
+def encode_dates(data: Mapping[str, np.ndarray]
+                 ) -> Tuple[Dict[str, np.ndarray], Tuple[str, ...]]:
+    """Encode every ``datetime64`` column of a host column dict as int32
+    days; returns ``(columns, date column names)``."""
+    cols = {name: np.asarray(arr) for name, arr in data.items()}
+    dates = tuple(sorted(n for n, a in cols.items() if a.dtype.kind == "M"))
+    for name in dates:
+        cols[name] = date_days(cols[name], name=repr(name))
+    return cols, dates
+
+
+def decode_dates(cols: Mapping[str, np.ndarray], dates
+                 ) -> Dict[str, np.ndarray]:
+    """Host egress: the ``dates`` columns of ``cols`` as datetime64[D]."""
+    return {name: (np.asarray(v).astype("datetime64[D]") if name in dates
+                   else v) for name, v in cols.items()}
+
+
 def decode_columns(cols: Mapping[str, np.ndarray],
                    dicts: Mapping[str, Dictionary]) -> Dict[str, np.ndarray]:
     """Decode the dictionary-encoded columns of a host column dict."""
@@ -185,6 +224,46 @@ class _StrLit:
 
     def __init__(self, value: str):
         self.value = value
+
+
+class _DateLit:
+    """Marker meta for a raw date literal awaiting a date-column context."""
+
+    __slots__ = ("lit",)
+
+    def __init__(self, lit: Lit):
+        self.lit = lit
+
+
+#: meta of a date column's values (int32 days on the device)
+_DATE = "date"
+
+
+def _dated(meta) -> bool:
+    return meta is _DATE or isinstance(meta, _DateLit)
+
+
+def _day_lit(lit: Lit) -> Lit:
+    # a plain python int, as ``_code_lit``: the int32 day column keeps its
+    # dtype in the comparison
+    return Lit(int(lit.value.astype(np.int64)))
+
+
+def _lower_date(e: BinOp, l: Expr, lm, r: Expr, rm) -> Expr:
+    """A binary op with a date on either side: comparisons of two dates
+    only, a date literal becoming its day number."""
+    if e.op not in _COMPARE:
+        raise DictTypeError(f"{e.op!r} on a date value ({e!r}): dates "
+                            f"support comparisons with dates only")
+    if not (_dated(lm) and _dated(rm)):
+        raise DictTypeError(
+            f"cannot compare a date with a value that is not a date "
+            f"({e!r}); compare with datetime.date or np.datetime64")
+    if isinstance(lm, _DateLit):
+        l = _day_lit(lm.lit)
+    if isinstance(rm, _DateLit):
+        r = _day_lit(rm.lit)
+    return BinOp(e.op, l, r)
 
 
 def _code_lit(v: int) -> Lit:
@@ -230,18 +309,26 @@ def _lower_compare(op: str, cexpr: Expr, d: Dictionary, s: str,
     raise AssertionError(op)
 
 
-def _lower(e: Expr, dicts: Mapping[str, Dictionary]):
+def _lower(e: Expr, dicts: Mapping[str, Dictionary], dates=frozenset()):
     """Recursive lowering: returns ``(expr, meta)`` where meta is ``None``
     (numeric value), a ``Dictionary`` (value is codes in that dictionary),
-    or ``_StrLit`` (raw string literal, resolved by an enclosing compare)."""
+    ``_StrLit`` (raw string literal, resolved by an enclosing compare),
+    ``_DATE`` (a date column's days) or ``_DateLit`` (raw date literal)."""
     if isinstance(e, Col):
+        if e.name in dates:
+            return e, _DATE
         return e, dicts.get(e.name)
     if isinstance(e, Lit):
         if isinstance(e.value, (str, np.str_)):
             return e, _StrLit(str(e.value))
+        if isinstance(e.value, np.datetime64):
+            return e, _DateLit(e)
         return e, None
     if isinstance(e, UnaryOp):
-        op, meta = _lower(e.operand, dicts)
+        op, meta = _lower(e.operand, dicts, dates)
+        if _dated(meta):
+            raise DictTypeError(f"unary {e.op!r} on a date value ({e!r}): "
+                                f"dates support comparisons only")
         if meta is not None:
             raise DictTypeError(
                 f"unary {e.op!r} on a dictionary-encoded string value "
@@ -250,15 +337,21 @@ def _lower(e: Expr, dicts: Mapping[str, Dictionary]):
     if isinstance(e, IsNull):
         # null-ness lives in the validity mask, not the codes: defined for
         # every column type, always a plain boolean result
-        op, meta = _lower(e.operand, dicts)
-        if isinstance(meta, _StrLit):
+        op, meta = _lower(e.operand, dicts, dates)
+        if isinstance(meta, (_StrLit, _DateLit)):
             op = _code_lit(0)          # a literal is never null
         return IsNull(op), None
     if isinstance(e, FillNull):
-        op, om = _lower(e.operand, dicts)
-        fl, fm = _lower(e.fill, dicts)
-        if isinstance(om, _StrLit):    # literal operand: never null
+        op, om = _lower(e.operand, dicts, dates)
+        fl, fm = _lower(e.fill, dicts, dates)
+        if isinstance(om, (_StrLit, _DateLit)):  # literal operand: never null
             return op, om
+        if om is _DATE or _dated(fm):
+            if not (om is _DATE and _dated(fm)):
+                raise DictTypeError(f"fill_null mixes a date with a value "
+                                    f"that is not a date ({e!r})")
+            return FillNull(op, _day_lit(fm.lit) if fm is not _DATE
+                            else fl), _DATE
         if isinstance(om, tuple):
             if isinstance(fm, _StrLit):
                 s = fm.value
@@ -296,10 +389,12 @@ def _lower(e: Expr, dicts: Mapping[str, Dictionary]):
                 f"so string literals can be lowered against the dictionary")
         return e, None
     if isinstance(e, BinOp):
-        l, lm = _lower(e.left, dicts)
-        r, rm = _lower(e.right, dicts)
+        l, lm = _lower(e.left, dicts, dates)
+        r, rm = _lower(e.right, dicts, dates)
         if lm is None and rm is None:
             return BinOp(e.op, l, r), None
+        if _dated(lm) or _dated(rm):
+            return _lower_date(e, l, lm, r, rm), None
         if e.op in _COMPARE:
             if isinstance(lm, tuple) and isinstance(rm, _StrLit):
                 return _lower_compare(e.op, l, lm, rm.value, swap=False), None
@@ -324,19 +419,24 @@ def _lower(e: Expr, dicts: Mapping[str, Dictionary]):
     raise TypeError(f"cannot lower {type(e).__name__}")
 
 
-def lower_expr(e: Expr, dicts: Mapping[str, Dictionary]
-               ) -> Tuple[Expr, Optional[Dictionary]]:
+def lower_expr(e: Expr, dicts: Mapping[str, Dictionary],
+               dates=frozenset()) -> Tuple[Expr, Optional[Dictionary]]:
     """Lower string literals in ``e`` against the input's per-column
-    ``dicts``; returns ``(lowered expr, output dictionary or None)``.
+    ``dicts``, and date literals compared with the input's ``dates``
+    columns to int32 days; returns ``(lowered expr, output dictionary or
+    None)``.
 
     A bare string literal becomes a constant column over the singleton
-    dictionary ``(s,)`` (code 0).  Raises ``DictTypeError`` for operations
-    with no dictionary-code semantics (arithmetic on strings, mixed-type
-    comparisons, cross-dictionary column comparisons).
+    dictionary ``(s,)`` (code 0); a bare date literal stays as it is (a
+    constant date column).  Raises ``DictTypeError`` for operations with
+    no dictionary-code or date semantics (arithmetic on strings or dates,
+    mixed-type comparisons, cross-dictionary column comparisons).
     """
-    out, meta = _lower(e, dicts)
+    out, meta = _lower(e, dicts, dates)
     if isinstance(meta, _StrLit):
         return _code_lit(0), (meta.value,)
+    if _dated(meta):
+        return out, None
     return out, meta
 
 
@@ -354,3 +454,15 @@ def expr_dictionary(e: Expr, dicts: Mapping[str, Dictionary]
     if isinstance(e, FillNull):
         return expr_dictionary(e.operand, dicts)
     return None
+
+
+def expr_is_date(e: Expr, dates) -> bool:
+    """True when an expression's output is a date: a date column passed
+    through, a date literal, or a filled date column."""
+    if isinstance(e, Col):
+        return e.name in dates
+    if isinstance(e, Lit):
+        return isinstance(e.value, np.datetime64)
+    if isinstance(e, FillNull):
+        return expr_is_date(e.operand, dates)
+    return False

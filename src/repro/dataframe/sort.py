@@ -15,21 +15,25 @@ import jax.numpy as jnp
 
 from ..comm import Communicator
 from ..nulls import mask_name
-from .ops_local import sort_local
+from .ops_local import descending_key, sort_local
 from .shuffle import ShuffleStats, shuffle
 from .table import Table, _sentinel_for
 
 
 def _range_dest(table: Table, key_col: str, comm: Communicator,
-                samples: int) -> jax.Array:
+                samples: int, descending: bool = False) -> jax.Array:
     """Destination ranks for a range partition on ``key_col``.
 
     Nulls-last semantics: null keys are excluded from the splitter sample
     (their canonical-zero values would skew the quantiles toward rank 0)
     and routed to the last rank, where the local sort puts them at the
-    tail — the global order ends ... , max, null, null."""
+    tail — the global order ends ... , max, null, null.  ``descending``
+    partitions the key's ``descending_key``, so rank 0 holds the largest
+    keys (nulls still last)."""
     p = comm.size()
     key = table.columns[key_col]
+    if descending:
+        key = descending_key(key)
     m = table.columns.get(mask_name(key_col))
     valid = table.valid_mask()
     if m is None:
@@ -70,17 +74,32 @@ def sort(
     comm: Communicator,
     by: Sequence[str],
     samples: int = 64,
+    ascending: Optional[Sequence[bool]] = None,
     **shuffle_kw,
 ) -> Tuple[Table, ShuffleStats]:
-    """Globally sort by ``by[0]`` across ranks (full lexsort within rank).
+    """Globally sort by ``by`` across ranks (full lexsort within rank).
 
-    Rank r holds the r-th contiguous key range; within a rank rows are
-    lex-sorted by all of ``by``.  (Distributed tie order across ranks follows
-    the primary key only — the paper's benchmark sorts single int columns.)
+    Rank r holds the r-th contiguous range of ``by[0]``, every copy of a
+    key on one rank; within a rank rows are lex-sorted by all of ``by``, so
+    the concatenation of the ranks is in global order.  ``ascending`` gives
+    each key's direction (default: all ascending).
     """
-    dest = _range_dest(table, by[0], comm, samples)
+    dest = _range_dest(table, by[0], comm, samples,
+                       descending=ascending is not None and not ascending[0])
     shuffled, stats = shuffle(table, comm, dest=dest, **shuffle_kw)
-    return sort_local(shuffled, by), stats
+    return sort_local(shuffled, by, ascending), stats
+
+
+def limit(table: Table, comm: Communicator, n: int) -> Table:
+    """The first ``n`` rows in global order: rank order, then row order
+    within each rank (the order ``sort`` leaves).  Each rank keeps what is
+    left of ``n`` after the rows of the ranks before it, the exclusive
+    prefix of the row counts; no row moves."""
+    counts = comm.all_gather(table.row_count[None]).reshape(-1)
+    before = jnp.sum(jnp.where(jnp.arange(counts.shape[0]) < comm.rank(),
+                               counts, 0))
+    keep = jnp.clip(n - before, 0, table.row_count).astype(jnp.int32)
+    return Table(table.columns, keep).mask_padding()
 
 
 def repartition_balanced(

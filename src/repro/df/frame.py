@@ -186,12 +186,23 @@ class DataFrame:
         self._check_cols(keys, "groupby keys")
         return GroupBy(self, keys, kw)
 
-    def sort_values(self, by: Union[str, Sequence[str]], **kw) -> "DataFrame":
-        """Globally sort (ascending) by column(s): sample-sort range
-        partitioning + local sort."""
+    def sort_values(self, by: Union[str, Sequence[str]],
+                    ascending: Union[bool, Sequence[bool]] = True,
+                    **kw) -> "DataFrame":
+        """Globally sort by column(s): sample-sort range partitioning +
+        local sort.  ``ascending`` is one flag, or one per column, as in
+        pandas; nulls sort last either way."""
         by = [by] if isinstance(by, str) else list(by)
         self._check_cols(by, "sort_values")
-        return self._derive(self.plan.sort(by, **kw), self._schema)
+        if isinstance(ascending, (bool, np.bool_)):
+            ascending = [bool(ascending)] * len(by)
+        return self._derive(self.plan.sort(by, ascending=ascending, **kw),
+                            self._schema)
+
+    def head(self, n: int = 5) -> "DataFrame":
+        """The first ``n`` rows in global order (SQL ``LIMIT``): after
+        ``sort_values``, its top ``n``.  Lazy like every transformation."""
+        return self._derive(self.plan.limit(n), self._schema)
 
     # -- missing data ---------------------------------------------------- #
     def dropna(self, subset: Optional[Sequence[str]] = None) -> "DataFrame":

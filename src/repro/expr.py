@@ -33,6 +33,7 @@ fingerprints by bytecode + captured values, the best a callable allows.
 
 from __future__ import annotations
 
+import datetime
 import hashlib
 from typing import Any, Callable, FrozenSet, Optional, Sequence
 
@@ -333,6 +334,21 @@ class Col(Expr):
         return self.name
 
 
+def _date_value(value) -> Optional[np.datetime64]:
+    """``datetime.date`` / ``np.datetime64`` -> ``datetime64[D]``; None for
+    any other value.  A time of day is refused rather than truncated."""
+    if not isinstance(value, (datetime.date, np.datetime64)):
+        return None
+    day = np.datetime64(value, "D")
+    if np.isnat(day):
+        raise TypeError("NaT is not a date literal; test for nulls with "
+                        "is_null()")
+    if np.datetime64(value) != day:
+        raise TypeError(f"date literal {value!r} has a time of day; only "
+                        f"whole dates are supported")
+    return day
+
+
 class Lit(Expr):
     """Literal scalar.  Python scalars stay weakly typed (so ``col + 1.0``
     follows jnp's weak-promotion rules, matching what inline jnp code would
@@ -341,7 +357,10 @@ class Lit(Expr):
     String literals are allowed in the tree (``col("s") == "oak"``) but
     never reach the device: the planner lowers them into int32 code
     comparisons against the column's dictionary
-    (``dataframe.schema.lower_expr``) before compilation."""
+    (``dataframe.schema.lower_expr``) before compilation.  Date literals
+    (``datetime.date`` / ``np.datetime64``) are held as ``datetime64[D]``;
+    compared with a date column they lower to int32 days since 1970-01-01,
+    the device type of dates."""
 
     __slots__ = ("value",)
 
@@ -350,7 +369,8 @@ class Lit(Expr):
             raise TypeError("lit() of an Expr — pass a scalar")
         if isinstance(value, (np.ndarray, jax.Array)) and np.ndim(value) != 0:
             raise TypeError("lit() takes a scalar, not an array")
-        object.__setattr__(self, "value", value)
+        day = _date_value(value)
+        object.__setattr__(self, "value", value if day is None else day)
 
     def __setattr__(self, *_):
         raise AttributeError("Expr nodes are immutable")
@@ -376,6 +396,8 @@ class Lit(Expr):
                 f"literals are only usable in comparisons against a "
                 f"dictionary-encoded column (the planner lowers them — "
                 f"see docs/data_model.md)")
+        if isinstance(self.value, np.datetime64):
+            return np.int32(self.value.astype(np.int64))  # days
         return self.value  # jnp ops promote python scalars weakly
 
     def nullable(self, nulls) -> bool:
@@ -639,10 +661,12 @@ def ensure_expr(v: Any) -> Expr:
     """Lift scalars to ``Lit``; pass ``Expr`` through; reject the rest.
 
     Strings lift too (``col("s") == "oak"``): they are lowered into
-    dictionary-code comparisons by the planner, never evaluated raw."""
+    dictionary-code comparisons by the planner, never evaluated raw.  So do
+    dates (``col("d") < datetime.date(1995, 3, 15)``)."""
     if isinstance(v, Expr):
         return v
-    if isinstance(v, (bool, int, float, complex, str, np.generic)):
+    if isinstance(v, (bool, int, float, complex, str, np.generic,
+                      datetime.date)):
         return Lit(v)
     if isinstance(v, (np.ndarray, jax.Array)) and np.ndim(v) == 0:
         return Lit(v)

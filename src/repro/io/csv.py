@@ -199,5 +199,6 @@ def read_csv(source: Union[str, os.PathLike, Sequence],
     spill.provenance = IngestInfo(
         format="csv", files=files, rows=builder.rows,
         bytes_read=sum(os.path.getsize(f) for f in files), batches=batches,
-        recodes=builder.recodes, dict_cache_hit=cached is not None)
+        recodes=builder.recodes, dict_cache_hit=cached is not None,
+        dates=spill.dates)
     return spill

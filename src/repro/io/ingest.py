@@ -20,7 +20,13 @@ builder owns everything format-independent:
   was ever null, so chunk schemas stay uniform;
 * **numeric widening** — a column that arrives int64 in one batch and
   float64 in another (CSV fallback lane) is unified to float64 at
-  ``finalize``.
+  ``finalize``;
+* **dates** — ``datetime64`` columns (Parquet ``date32``) become int32
+  days since 1970-01-01, named in the table's ``dates``;
+* **64-bit integers** — the device holds 32-bit integers (x64 is off), so
+  an int64 value outside the int32 range (a uint64 value outside the
+  uint32 range) is refused here, naming its column, instead of wrapping
+  when the table is placed (``core.env.put_rows``).
 
 ``DictionaryCache`` is the process-level cache keyed by the *source
 signature* (paths + sizes + mtimes): a second read of an unchanged source
@@ -41,7 +47,7 @@ import numpy as np
 
 from ..core.store import SpillTable
 from ..dataframe.schema import (CODE_DTYPE, Dictionary, _as_str_array,
-                                recode_mapping)
+                                date_days, recode_mapping)
 from ..nulls import check_reserved_names, mask_name
 
 __all__ = ["IngestInfo", "DictionaryCache", "DICT_CACHE", "TableBuilder",
@@ -112,11 +118,13 @@ class IngestInfo:
     batches: int                  # chunks streamed through the builder
     recodes: int                  # stale-dictionary chunk recodes at finalize
     dict_cache_hit: bool = False
+    dates: Tuple[str, ...] = ()   # date columns decoded to int32 days
 
     def summary(self) -> str:
+        dates = f", dates {','.join(self.dates)}" if self.dates else ""
         return (f"{self.format}: {len(self.files)} "
                 f"file{'s' if len(self.files) != 1 else ''}, "
-                f"~{self.rows} rows")
+                f"~{self.rows} rows{dates}")
 
     def __str__(self) -> str:
         return self.summary()
@@ -175,9 +183,11 @@ def arrow_batch_columns(batch) -> Tuple[Dict[str, np.ndarray],
     ``TableBuilder.add_batch``.
 
     Numeric/bool columns keep their dtype (nulls filled with the canonical
-    zero via Arrow's validity bitmap, never a float widen); string columns
-    come out as object arrays with null slots holding a placeholder ``""``
-    (the builder excludes them from the dictionary and zeroes their codes).
+    zero via Arrow's validity bitmap, never a float widen); ``date32``
+    columns come out as ``datetime64[D]`` (null slots at the epoch); string
+    columns come out as object arrays with null slots holding a placeholder
+    ``""`` (the builder excludes them from the dictionary and zeroes their
+    codes).
     """
     import pyarrow as pa
     cols: Dict[str, np.ndarray] = {}
@@ -203,14 +213,36 @@ def arrow_batch_columns(batch) -> Tuple[Dict[str, np.ndarray],
                 col, pa.scalar(False if pa.types.is_boolean(t) else 0,
                                type=t))
             arr = filled.to_numpy(zero_copy_only=False)
+        elif pa.types.is_date32(t):
+            days = col.cast(pa.int32())
+            if nulls:
+                days = pa.compute.fill_null(days, 0)
+            arr = days.to_numpy(zero_copy_only=False).astype("datetime64[D]")
         else:
             raise TypeError(
                 f"column {name!r} has unsupported Arrow type {t}; "
-                f"supported: integer, floating, boolean, string")
+                f"supported: integer, floating, boolean, string, date32")
         cols[name] = arr
         if valid is not None:
             valids[name] = valid
     return cols, valids
+
+
+def _check_narrowing(name: str, arr: np.ndarray) -> None:
+    """Refuse 64-bit integers that the 32-bit device types cannot hold
+    (x64 off: int64 becomes int32 and uint64 uint32 on placement)."""
+    import jax
+    if (arr.dtype.kind not in "iu" or arr.dtype.itemsize <= 4
+            or not len(arr) or jax.config.jax_enable_x64):
+        return
+    narrow = np.iinfo(np.int32 if arr.dtype.kind == "i" else np.uint32)
+    lo, hi = arr.min(), arr.max()
+    if lo < narrow.min or hi > narrow.max:
+        raise ValueError(
+            f"column {name!r} holds {arr.dtype} values outside the "
+            f"{narrow.dtype} range [{narrow.min}, {narrow.max}] (min {lo}, "
+            f"max {hi}); the device holds 32-bit integers, so they would "
+            f"wrap")
 
 
 class _Chunk:
@@ -244,6 +276,7 @@ class TableBuilder:
         self._chunks: List[_Chunk] = []
         self._names: Optional[Tuple[str, ...]] = None
         self._string_cols: set = set()
+        self._date_cols: set = set()
         self._nullable: set = set()
         # running dictionary per string column + its snapshot history
         self._dicts: Dict[str, np.ndarray] = {
@@ -304,6 +337,8 @@ class TableBuilder:
             from ..dataframe.schema import is_string_array
             self._string_cols = {n for n, a in cols.items()
                                  if is_string_array(np.asarray(a))}
+            self._date_cols = {n for n, a in cols.items()
+                               if np.asarray(a).dtype.kind == "M"}
         elif set(names) != set(self._names):
             raise ValueError(
                 f"batch schema {sorted(names)} != ingest schema "
@@ -326,9 +361,12 @@ class TableBuilder:
                 out_cols[name] = self._encode_strings(name, arr, valid)
                 dictver[name] = len(self._snapshots[name]) - 1
             else:
+                if name in self._date_cols:
+                    arr = date_days(arr, name=repr(name))
                 if valid is not None:
                     arr = arr.copy()
                     arr[~valid] = 0   # canonical zero (0 / 0.0 / False)
+                _check_narrowing(name, arr)
                 out_cols[name] = arr
             if valid is not None:
                 out_valid[name] = valid
@@ -372,7 +410,8 @@ class TableBuilder:
         validity masks, and append everything round-robin into a
         ``SpillTable``.  The builder is spent afterwards."""
         dicts = self.final_dictionaries()
-        spill = SpillTable(self.parallelism, dictionaries=dicts)
+        spill = SpillTable(self.parallelism, dictionaries=dicts,
+                           dates=self._date_cols)
         if not self._chunks:
             return spill
         dtypes = self._unified_dtypes()

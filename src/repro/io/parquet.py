@@ -45,6 +45,7 @@ def _empty_table(pq, files, parallelism: int,
     sch = pq.ParquetFile(files[0]).schema_arrow
     schema = {}
     dicts = {}
+    dates = []
     for field in sch:
         if columns is not None and field.name not in columns:
             continue
@@ -52,9 +53,13 @@ def _empty_table(pq, files, parallelism: int,
                 pa.types.is_large_string(field.type):
             schema[field.name] = (np.dtype(np.int32), ())
             dicts[field.name] = ("",)
+        elif pa.types.is_date32(field.type):
+            schema[field.name] = (np.dtype(np.int32), ())
+            dates.append(field.name)
         else:
             schema[field.name] = (np.dtype(field.type.to_pandas_dtype()), ())
-    return SpillTable(parallelism, schema=schema, dictionaries=dicts)
+    return SpillTable(parallelism, schema=schema, dictionaries=dicts,
+                      dates=dates)
 
 
 def read_parquet(source: Union[str, os.PathLike, Sequence],
@@ -74,7 +79,10 @@ def read_parquet(source: Union[str, os.PathLike, Sequence],
 
     Nulls become ``__m_*`` validity masks with canonical-zero data slots
     (``docs/data_model.md``); int/bool columns keep their dtype (no float
-    widen at ingest).
+    widen at ingest).  ``date32`` columns become int32 days since
+    1970-01-01, named in the table's ``dates`` and in ``provenance.dates``.
+    64-bit integers outside the 32-bit range the device holds raise
+    ``ValueError`` naming the column.
     """
     pq = _require_pyarrow()
     files = expand_paths(source)
@@ -105,5 +113,5 @@ def read_parquet(source: Union[str, os.PathLike, Sequence],
     spill.provenance = IngestInfo(
         format="parquet", files=files, rows=builder.rows,
         bytes_read=bytes_read, batches=batches, recodes=builder.recodes,
-        dict_cache_hit=cached is not None)
+        dict_cache_hit=cached is not None, dates=spill.dates)
     return spill

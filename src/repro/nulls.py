@@ -77,6 +77,8 @@ def _valid_of(arr: np.ndarray) -> np.ndarray:
     """Element-is-valid for a host array: NaN and None are null."""
     if arr.dtype.kind == "f":
         return ~np.isnan(arr)
+    if arr.dtype.kind == "M":
+        return ~np.isnat(arr)
     if arr.dtype.kind == "O":
         # None / float NaN / pandas NA inside an object column are null
         def ok(x):
@@ -94,7 +96,7 @@ def extract_null_columns(data: Dict[str, np.ndarray]
     """Host-side ingest normalization: NaN / None become explicit masks.
 
     For every data column, null slots are canonicalized — floats to ``0.0``,
-    object (string) columns to their lexicographically smallest valid value
+    dates (NaT) to the epoch, object (string) columns to their lexicographically smallest valid value
     (so the later dictionary encode assigns them code 0 without polluting
     the dictionary).  Pre-supplied ``__m_*`` columns are validated, cast to
     bool, and their bases canonicalized too.  Columns with no nulls and no
@@ -138,8 +140,8 @@ def apply_null_columns(cols: Dict[str, np.ndarray]
     """Host-side output: re-materialize masks as pandas-style missing values.
 
     Floats get NaN; integers are widened to float64 with NaN (pandas
-    behaviour for nullable ints); object/string columns get ``None``;
-    booleans widen to object with ``None``.  Mask columns are consumed.
+    behaviour for nullable ints); dates get NaT; object/string columns get
+    ``None``; booleans widen to object with ``None``.  Mask columns are consumed.
     A column whose mask is all-True still widens (nullability is a schema
     property, not a data property) so dtypes are stable across batches.
     """
@@ -159,6 +161,9 @@ def apply_null_columns(cols: Dict[str, np.ndarray]
         elif arr.dtype.kind in "iu":
             a = arr.astype(np.float64)
             a[~valid] = np.nan
+        elif arr.dtype.kind == "M":
+            a = arr.copy()
+            a[~valid] = np.datetime64("NaT")
         else:
             a = arr.astype(object)
             a[~valid] = None

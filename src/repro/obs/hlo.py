@@ -18,7 +18,7 @@ SHUFFLE = "shuffle"
 #: scopes ``eval_node`` opens: one per logical plan operator
 OPERATOR_SCOPES = frozenset({
     "scan", "noop", "project", "filter", "with_columns", "add_scalar",
-    "recode", "shuffle", "join", "groupby", "sort"})
+    "recode", "shuffle", "join", "groupby", "sort", "limit"})
 
 _COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([^\s(]+)\s*\(.*\{\s*$")
 _INSTRUCTION = re.compile(r"^\s+(?:ROOT\s+)?%?([^\s=]+) = (.*)$")

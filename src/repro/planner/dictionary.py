@@ -14,18 +14,20 @@ because it is a *correctness* pass, not a rewrite heuristic (it fires with
    compiled program like any local operator.  Equal keys then share codes
    gang-wide, so hashing/sorting/merging codes is exact.
 
-2. **String-literal lowering** — ``filter`` / ``with_columns`` expressions
-   containing string literals are rewritten into int32 code comparisons
-   against the input's dictionary (``dataframe.schema.lower_expr``):
-   ``col("s") < "oak"`` becomes ``s < lit(int32(k))`` via searchsorted on
-   the sorted dictionary.  The lowered literal is part of the expression
-   fingerprint, so different dictionaries compile distinct programs.
+2. **String- and date-literal lowering** — ``filter`` / ``with_columns``
+   expressions containing string literals are rewritten into int32 code
+   comparisons against the input's dictionary
+   (``dataframe.schema.lower_expr``): ``col("s") < "oak"`` becomes
+   ``s < lit(int32(k))`` via searchsorted on the sorted dictionary.  The
+   lowered literal is part of the expression fingerprint, so different
+   dictionaries compile distinct programs.  A date literal compared with a
+   date column (``LogicalNode.dates``) becomes its int32 day number.
 
 3. **Validation** — operations with no dictionary-code semantics raise
    ``DictTypeError`` at compile time with a message naming the column:
-   arithmetic on string columns, sum/mean aggregates over them, string
-   vs numeric comparisons, and joins of a string key against a numeric
-   key.
+   arithmetic on string or date columns, sum/mean aggregates over them,
+   string or date vs numeric comparisons, and joins of a string key
+   against a numeric key.
 
 The pass mutates the logical DAG in place (the builder tree the user holds
 is never touched — ``from_plan`` copies params) and returns EXPLAIN-style
@@ -96,8 +98,9 @@ def _lower_exprs(root: LogicalNode) -> None:
     for n in topo(root):
         p = n.params
         dicts = n.inputs[0].dicts if n.inputs else {}
+        dates = n.inputs[0].dates if n.inputs else frozenset()
         if n.op == "filter":
-            lowered, out_dict = lower_expr(p["expr"], dicts)
+            lowered, out_dict = lower_expr(p["expr"], dicts, dates)
             if out_dict is not None:
                 raise DictTypeError(
                     f"filter predicate {p['expr']!r} yields a string value, "
@@ -108,7 +111,7 @@ def _lower_exprs(root: LogicalNode) -> None:
             # with the user's builder tree (from_plan is a shallow copy)
             exprs, assign_dicts = {}, {}
             for name, e in p["exprs"].items():
-                exprs[name], d = lower_expr(e, dicts)
+                exprs[name], d = lower_expr(e, dicts, dates)
                 if d is not None:
                     assign_dicts[name] = d
             p["exprs"] = exprs
@@ -120,26 +123,29 @@ def _validate(root: LogicalNode) -> None:
     for n in topo(root):
         p = n.params
         dicts = n.inputs[0].dicts if n.inputs else {}
+        dates = n.inputs[0].dates if n.inputs else frozenset()
         if n.op == "groupby":
             for col, agg_names in p["aggs"].items():
-                if col not in dicts:
+                if col not in dicts and col not in dates:
                     continue
+                kind = ("dictionary-encoded string" if col in dicts
+                        else "date")
                 bad = [a for a in agg_names
                        if a not in ("min", "max", "count")]
                 if bad:
                     raise DictTypeError(
                         f"aggregate(s) {bad} are not defined on the "
-                        f"dictionary-encoded string column {col!r}; "
-                        f"supported: min, max, count")
+                        f"{kind} column {col!r}; supported: min, max, count")
         elif n.op == "add_scalar":
             touched = p.get("cols")
-            touched = set(dicts if touched is None else touched)
-            bad = sorted(touched & set(dicts))
+            schema = n.inputs[0].schema
+            touched = set(schema if touched is None else touched)
+            bad = sorted(touched & (set(dicts) | set(dates)))
             if bad:
                 raise DictTypeError(
-                    f"add_scalar touches dictionary-encoded string "
-                    f"column(s) {bad}; arithmetic is not defined on "
-                    f"strings — pass cols= to restrict it")
+                    f"add_scalar touches string or date column(s) {bad}; "
+                    f"arithmetic is not defined on them — pass cols= to "
+                    f"restrict it")
 
 
 def apply_dictionaries(root: LogicalNode) -> List[str]:

@@ -59,7 +59,11 @@ def _label(n: LogicalNode) -> str:
         return f"groupby[{','.join(p['keys'])}; {aggs}]{extra}"
     if n.op == "sort":
         extra = " (shuffle-elided)" if p.get("elide_shuffle") else ""
-        return f"sort[{','.join(p['by'])}]{extra}"
+        asc = p.get("ascending") or (True,) * len(p["by"])
+        keys = ",".join(b if a else f"{b} desc" for b, a in zip(p["by"], asc))
+        return f"sort[{keys}]{extra}"
+    if n.op == "limit":
+        return f"limit[{p['n']}]"
     return n.op
 
 

@@ -17,7 +17,8 @@ The partitioning lattice is what makes shuffle elision sound:
   same columns are co-partitioned.
 * ``range(c)`` — rank ``r`` holds the ``r``-th contiguous key range of ``c``
   (sample-sort splitters); equal keys are co-located but *not* aligned with
-  any hash partitioning.
+  any hash partitioning.  ``range(c desc)`` holds the ranges in descending
+  order (rank 0 the largest keys).
 
 ``colocates(cols)`` (equal keys share a rank) is the requirement of
 ``groupby``; ``matches_hash`` (exact placement equality) is the stronger
@@ -34,9 +35,10 @@ from typing import Any, Dict, List, Mapping, Optional, Sequence, Tuple
 #: ops that may execute a shuffle (communication boundaries)
 COMM_OPS = ("shuffle", "join", "groupby", "sort")
 #: purely local ops (``recode`` remaps dictionary codes via a static
-#: gather table — inserted by ``planner.dictionary``, never by users)
+#: gather table — inserted by ``planner.dictionary``, never by users;
+#: ``limit`` reads every rank's row count, a scalar, and moves no rows)
 LOCAL_OPS = ("scan", "project", "filter", "with_columns", "add_scalar",
-             "recode", "noop")
+             "recode", "noop", "limit")
 
 #: paper §V data recipe: ~90% key cardinality (drives groupby estimates)
 DEFAULT_GROUP_RATIO = 0.9
@@ -50,6 +52,7 @@ _ids = itertools.count()
 class Partitioning:
     kind: str = "none"            # "none" | "hash" | "range"
     cols: Tuple[str, ...] = ()
+    descending: bool = False      # range only: rank 0 holds the largest keys
 
     @staticmethod
     def none() -> "Partitioning":
@@ -60,8 +63,8 @@ class Partitioning:
         return Partitioning("hash", tuple(cols))
 
     @staticmethod
-    def range_(col: str) -> "Partitioning":
-        return Partitioning("range", (col,))
+    def range_(col: str, descending: bool = False) -> "Partitioning":
+        return Partitioning("range", (col,), descending)
 
     def colocates(self, cols: Sequence[str]) -> bool:
         """Rows with equal values on ``cols`` are guaranteed to share a rank."""
@@ -72,9 +75,11 @@ class Partitioning:
         """Placement is exactly ``hash_columns(cols) % p``."""
         return self.kind == "hash" and self.cols == tuple(cols)
 
-    def matches_range(self, col: str) -> bool:
-        """Rank r holds the r-th contiguous range of ``col``."""
-        return self.kind == "range" and self.cols == (col,)
+    def matches_range(self, col: str, descending: bool = False) -> bool:
+        """Rank r holds the r-th contiguous range of ``col``, in the
+        order ``descending`` says."""
+        return (self.kind == "range" and self.cols == (col,)
+                and self.descending == descending)
 
     def restrict(self, live: Sequence[str]) -> "Partitioning":
         """Drop the property if its columns are no longer live."""
@@ -85,7 +90,8 @@ class Partitioning:
     def __str__(self) -> str:
         if self.kind == "none":
             return "none"
-        return f"{self.kind}({','.join(self.cols)})"
+        desc = " desc" if self.descending else ""
+        return f"{self.kind}({','.join(self.cols)}{desc})"
 
 
 @dataclasses.dataclass
@@ -106,6 +112,8 @@ class LogicalNode:
     #: optimizer uses ``c not in nulls`` to elide mask work, never the
     #: reverse, so over-approximating nullability is always sound.
     nulls: frozenset = frozenset()
+    #: output columns that hold dates (int32 days since 1970-01-01)
+    dates: frozenset = frozenset()
     nid: int = dataclasses.field(default_factory=lambda: next(_ids))
 
     # -- physical classification (consulted by lowering & staging) ------- #
@@ -213,10 +221,13 @@ def _annotate_node(n: LogicalNode, catalog) -> None:
                 # ingest provenance summary (repro.io) — EXPLAIN renders
                 # ``scan[parquet: N files, ~M rows]``
                 n.params.setdefault("source", entry[4])
+            n.dates = frozenset(entry[5]) if len(entry) > 5 else frozenset()
         n.partitioning = Partitioning.none()  # block-distributed source
         return
 
     i0 = ins[0]
+    # dates pass through every op unless it drops or reassigns them
+    n.dates = i0.dates
     if n.op == "noop":                        # identity left by shuffle elision
         n.schema, n.partitioning, n.est_rows = i0.schema, i0.partitioning, i0.est_rows
         n.dicts = dict(i0.dicts)
@@ -227,6 +238,7 @@ def _annotate_node(n: LogicalNode, catalog) -> None:
         n.est_rows = i0.est_rows
         n.dicts = _restrict_dicts(i0.dicts, n.schema)
         n.nulls = i0.nulls & set(n.schema)
+        n.dates = i0.dates & set(n.schema)
     elif n.op == "filter":
         n.schema = i0.schema
         n.partitioning = i0.partitioning
@@ -236,7 +248,7 @@ def _annotate_node(n: LogicalNode, catalog) -> None:
     elif n.op == "with_columns":
         # assignments may introduce new columns; rewriting a partitioning
         # column's values breaks the placement property
-        from ..dataframe.schema import expr_dictionary
+        from ..dataframe.schema import expr_dictionary, expr_is_date
         assigned = set(p["exprs"])
         n.schema = tuple(sorted(set(i0.schema) | assigned))
         n.partitioning = (Partitioning.none()
@@ -259,6 +271,9 @@ def _annotate_node(n: LogicalNode, catalog) -> None:
             if nullable is None or nullable(i0.nulls):
                 nulls.add(name)
         n.nulls = frozenset(nulls)
+        n.dates = frozenset(i0.dates - assigned) | {
+            name for name, e in p["exprs"].items()
+            if expr_is_date(e, i0.dates)}
     elif n.op == "add_scalar":
         n.schema = i0.schema
         touched = p.get("cols")
@@ -311,6 +326,8 @@ def _annotate_node(n: LogicalNode, catalog) -> None:
                 continue
             nulls.add(c if c not in lcols else c + "_r")
         n.nulls = frozenset(nulls & set(n.schema))
+        n.dates = frozenset(l.dates | {c if c not in lcols else c + "_r"
+                                       for c in r.dates if c != p["on"]})
     elif n.op == "groupby":
         n.schema = groupby_schema(p["keys"], p["aggs"])
         if p.get("elide_shuffle"):
@@ -337,10 +354,23 @@ def _annotate_node(n: LogicalNode, catalog) -> None:
                     if a in ("min", "max", "mean"):
                         nulls.add(f"{col}_{a}")
         n.nulls = frozenset(nulls & set(n.schema))
+        # keys stay dates; so do the min and max of a date column
+        n.dates = frozenset(
+            {k for k in p["keys"] if k in i0.dates}
+            | {f"{col}_{a}" for col, agg_names in p["aggs"].items()
+               if col in i0.dates for a in agg_names if a in ("min", "max")})
     elif n.op == "sort":
         n.schema = i0.schema
-        n.partitioning = Partitioning.range_(p["by"][0])
+        n.partitioning = Partitioning.range_(
+            p["by"][0], descending=not p.get("ascending", (True,))[0])
         n.est_rows = i0.est_rows
+        n.dicts = dict(i0.dicts)
+        n.nulls = i0.nulls
+    elif n.op == "limit":
+        # a prefix of the input's rows in global order: every placement
+        # property still holds
+        n.schema, n.partitioning = i0.schema, i0.partitioning
+        n.est_rows = min(float(p["n"]), i0.est_rows)
         n.dicts = dict(i0.dicts)
         n.nulls = i0.nulls
     else:
@@ -403,7 +433,7 @@ def copy_dag(root: LogicalNode) -> LogicalNode:
         out = LogicalNode(n.op, [conv(i) for i in n.inputs], dict(n.params),
                           schema=n.schema, partitioning=n.partitioning,
                           est_rows=n.est_rows, dicts=dict(n.dicts),
-                          nulls=n.nulls)
+                          nulls=n.nulls, dates=n.dates)
         memo[n.nid] = out
         return out
 
@@ -415,8 +445,10 @@ def build_catalog(tables: Optional[Mapping[str, Any]]
                                        Dict[str, Tuple[str, ...]],
                                        frozenset]]:
     """Normalize scan metadata to ``(columns, est_rows, dictionaries,
-    nullable_columns[, source])`` — ``source`` is the ingest-provenance
-    summary string for tables read by ``repro.io`` (EXPLAIN label).
+    nullable_columns[, source, dates])`` — ``source`` is the
+    ingest-provenance summary string for tables read by ``repro.io``
+    (EXPLAIN label), ``dates`` the date columns (the holder's ``dates``, or
+    a raw dict's ``datetime64`` columns).
 
     Values may be DistTable-likes (``column_names`` + ``total_rows`` +
     optional ``dictionaries``), numpy column dicts, ``(cols, rows)`` pairs,
@@ -437,7 +469,8 @@ def build_catalog(tables: Optional[Mapping[str, Any]]
             prov = getattr(t, "provenance", None)
             cat[name] = (tuple(data_columns(names)), rows, dicts,
                          frozenset(nullable_columns(names)),
-                         str(prov) if prov is not None else None)
+                         str(prov) if prov is not None else None,
+                         frozenset(getattr(t, "dates", ()) or ()))
         elif isinstance(t, Mapping):
             # raw numpy column dict (morsel-streamed source): string
             # columns will be dictionary-encoded at ingest — mirror the
@@ -450,7 +483,7 @@ def build_catalog(tables: Optional[Mapping[str, Any]]
             # sources ingest once into a SpillTable/DistTable (which
             # carries .dictionaries) instead of passing raw dicts
             import numpy as _np
-            cols, dicts, rows = [], {}, 1024.0
+            cols, dicts, rows, dates = [], {}, 1024.0, set()
             nulls = set(nullable_columns(t.keys()))
             for cname, arr in t.items():
                 if cname.startswith("__m_"):
@@ -466,7 +499,10 @@ def build_catalog(tables: Optional[Mapping[str, Any]]
                     # all-null columns ingest as the "" fill value
                     dicts[cname] = (dictionary_of(vals) if len(vals)
                                     else ("",))
-            cat[name] = (tuple(cols), rows, dicts, frozenset(nulls))
+                if arr.dtype.kind == "M":
+                    dates.add(cname)
+            cat[name] = (tuple(cols), rows, dicts, frozenset(nulls), None,
+                         frozenset(dates))
         elif (isinstance(t, tuple) and len(t) in (2, 3, 4)
               and not isinstance(t[0], str)):
             dicts = dict(t[2]) if len(t) > 2 else {}

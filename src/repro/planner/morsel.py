@@ -135,6 +135,23 @@ def spine(pplan: PhysicalPlan) -> List[LogicalNode]:
     return chain
 
 
+def _refuse_in_core_only(pplan: PhysicalPlan) -> None:
+    """Refuse the nodes this executor has no combiner for, rather than
+    answer wrongly: ``limit`` (a global row prefix across morsels) and a
+    descending ``sort`` (the host splitters and rank sorts ascend)."""
+    for n in pplan.order:
+        if n.op == "limit":
+            raise NotImplementedError(
+                f"out-of-core execution does not run limit[{n.params['n']}] "
+                f"(head); collect it in core (no morsel_rows)")
+        if n.op == "sort" and not all(n.params.get("ascending", (True,))):
+            raise NotImplementedError(
+                f"out-of-core execution does not run a descending sort "
+                f"(sort[{','.join(n.params['by'])}], ascending="
+                f"{list(n.params['ascending'])}); collect it in core (no "
+                f"morsel_rows)")
+
+
 def segments(chain_tail: Sequence[LogicalNode]
              ) -> List[Tuple[List[LogicalNode], str]]:
     """Split the post-scan chain into morsel-program segments.
@@ -657,6 +674,7 @@ def run_morsel(pplan: PhysicalPlan, env, tables: Dict[str, Any],
         counters["retries"] += 1
 
     p = env.parallelism
+    _refuse_in_core_only(pplan)
     chain = spine(pplan)
     src_name = chain[0].params["name"]
     if src_name not in tables:

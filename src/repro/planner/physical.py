@@ -48,6 +48,7 @@ from ..dataframe.ops_local import hash_columns
 from ..dataframe.shuffle import ShuffleStats, _round_up
 from ..dataframe.shuffle import shuffle as df_shuffle
 from ..dataframe.sort import _range_dest
+from ..dataframe.sort import limit as df_limit
 from ..dataframe.sort import sort as df_sort
 from ..nulls import mask_name
 from ..dataframe.table import Table
@@ -58,7 +59,7 @@ _SEMANTIC = {
     "join": ("on", "out_capacity", "shuffle_out_capacity", "elide_left",
              "elide_right", "side_selected", "morsel_out_capacity"),
     "groupby": ("keys", "aggs", "elide_shuffle", "pre_aggregate"),
-    "sort": ("by", "elide_shuffle"),
+    "sort": ("by", "elide_shuffle", "ascending"),
     "shuffle": ("key_cols",),
 }
 
@@ -374,6 +375,8 @@ def _eval_node(node, comm, values, tables, shuffle_mode, stats_out,
         return ops_local.add_scalar(ins[0], p["value"], p.get("cols"))
     if node.op == "recode":
         return ops_local.recode(ins[0], p["cols"])
+    if node.op == "limit":
+        return df_limit(ins[0], comm, p["n"])
 
     kw = _shuffle_kw(node)
     if shuffle_mode == "direct":
@@ -453,20 +456,22 @@ def _eval_node(node, comm, values, tables, shuffle_mode, stats_out,
         return finalize_groupby(final, keys, post, nullable)
 
     if node.op == "sort":
-        by = p["by"]
+        by, ascending = p["by"], p.get("ascending")
         if p.get("elide_shuffle"):
-            return ops_local.sort_local(ins[0], by)
+            return ops_local.sort_local(ins[0], by, ascending)
         if shuffle_mode == "direct":
-            out, st = df_sort(ins[0], comm, by,
+            out, st = df_sort(ins[0], comm, by, ascending=ascending,
                               label=f"sort({','.join(by)})", **kw)
             if stats_out is not None:
                 stats_out.append((f"sort({','.join(by)})",
                                   _stat_vec(st, _row_bytes(ins[0]))))
             return out
-        dest = _range_dest(ins[0], by[0], comm, kw.pop("samples", 64))
+        dest = _range_dest(ins[0], by[0], comm, kw.pop("samples", 64),
+                           descending=ascending is not None
+                           and not ascending[0])
         shuffled = run_shuffle(f"sort({','.join(by)})", ins[0], dest=dest,
                                **kw)
-        return ops_local.sort_local(shuffled, by)
+        return ops_local.sort_local(shuffled, by, ascending)
 
     raise ValueError(node.op)
 
@@ -662,11 +667,15 @@ def attach_dictionaries(out, root: LogicalNode):
 
     The compiled programs move int32 codes only; the annotated root knows
     which output columns are dictionary-encoded and by what dictionary
-    (``LogicalNode.dicts``), so the driver restores the metadata here.
+    (``LogicalNode.dicts``), so the driver restores the metadata here, and
+    likewise which hold dates (``LogicalNode.dates``).
     """
     if root.dicts and hasattr(out, "dictionaries"):
         live = set(getattr(out, "column_names", ()) or root.dicts)
         out.dictionaries = {c: d for c, d in root.dicts.items() if c in live}
+    if root.dates and hasattr(out, "dates"):
+        live = set(getattr(out, "column_names", ()) or root.dates)
+        out.dates = tuple(sorted(root.dates & live))
     return out
 
 
@@ -692,6 +701,18 @@ def scan_read_stats(names: Sequence[str], tables: Dict[str, Any]
         if prov is not None:
             byts += int(getattr(prov, "bytes_read", 0))
     return rows, byts
+
+
+def default_scan_capacity(rows: int, parallelism: int) -> int:
+    """Per-rank slots for a host table placed for in-core execution: 2x a
+    balanced share of its rows (downstream shuffles inherit scan capacity,
+    and hash placement skews), rounded up to a multiple of 1/16 of its
+    power of two, which adds at most 1/8.  The rounding gives tables of
+    nearly the same size, such as one dataset drawn from other seeds, one
+    compiled program instead of one each."""
+    need = 2 * -(-max(rows, 1) // parallelism)
+    step = max(8, 1 << max(0, (need - 1).bit_length() - 4))
+    return -(-need // step) * step
 
 
 def _sum_stats(collected) -> Tuple[int, int, int]:
@@ -781,11 +802,10 @@ def run_physical(pplan: PhysicalPlan, env, tables: Dict[str, Any],
         raise KeyError(f"plan scans missing from tables: {missing}")
     check_scan_dictionaries(pplan.order, tables)
     # host-resident ingest sources (repro.io SpillTables) scatter onto the
-    # gang for in-core execution.  The default per-rank capacity leaves 2x
-    # headroom over a balanced split (downstream shuffles inherit scan
-    # capacity, and hash placement skews); ``scan_capacity`` overrides.
-    # Provenance rides along for the scan read stats.
-    from ..core.store import SpillTable, _round8
+    # gang for in-core execution at ``default_scan_capacity``;
+    # ``scan_capacity`` overrides.  Provenance rides along for the scan
+    # read stats.
+    from ..core.store import SpillTable
     from ..core.store import rescatter as _rescatter
     spills = {n: tables[n] for n in names
               if isinstance(tables[n], SpillTable)}
@@ -793,11 +813,13 @@ def run_physical(pplan: PhysicalPlan, env, tables: Dict[str, Any],
         def _cap(s):
             if scan_capacity is not None:
                 return scan_capacity
-            per = -(-max(s.total_rows(), 1) // env.parallelism)
-            return _round8(2 * per)
+            return default_scan_capacity(s.total_rows(), env.parallelism)
         def _place(n, s):
+            # a table read from files names the date columns its ingest
+            # decoded (``IngestInfo.dates``)
+            dates = {"dates": ",".join(s.dates)} if s.dates else {}
             with tr.span(f"place:{n}", "transfer", to_p=env.parallelism,
-                         rows=s.total_rows(), bytes=s.nbytes()):
+                         rows=s.total_rows(), bytes=s.nbytes(), **dates):
                 return _rescatter(s, env.parallelism, capacity=_cap(s),
                                   mesh=env.mesh)
         tables = {**tables, **{n: _place(n, s) for n, s in spills.items()}}
@@ -886,7 +908,7 @@ def run_physical(pplan: PhysicalPlan, env, tables: Dict[str, Any],
                 shuffle_impl=shuffle_impl, a2a_chunks=a2a_chunks, tracer=tr,
                 retries=policy, timeout=token,
                 overflow=OverflowPolicy.DEGRADE, faults=fr, adaptive=acfg)
-        except ValueError as e:
+        except (ValueError, NotImplementedError) as e:
             raise CapacityOverflow(
                 f"capacity pressure dropped {stats.rows_dropped} rows "
                 f"({describe_drops(stats.shuffle_records)}) and the plan "

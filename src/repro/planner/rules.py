@@ -75,8 +75,10 @@ def elide_shuffles(root: LogicalNode) -> List[str]:
                     f"shuffle-elision: groupby({','.join(p['keys'])}) runs "
                     f"local-only — input already {n.inputs[0].partitioning}")
         elif n.op == "sort" and not p.get("elide_shuffle"):
+            desc = not p.get("ascending", (True,))[0]
             if ("out_capacity" not in p
-                    and n.inputs[0].partitioning.matches_range(p["by"][0])):
+                    and n.inputs[0].partitioning.matches_range(
+                        p["by"][0], descending=desc)):
                 p["elide_shuffle"] = True
                 fired.append(
                     f"shuffle-elision: sort({','.join(p['by'])}) runs "
@@ -289,7 +291,7 @@ def _required_from(node: LogicalNode, required: Set[str], i: int) -> Set[str]:
     p = node.params
     if node.op in ("scan",):
         return set()
-    if node.op in ("project", "noop"):
+    if node.op in ("project", "noop", "limit"):
         return set(required)
     if node.op == "filter":
         cols = node.params["expr"].columns()
